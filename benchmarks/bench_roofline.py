@@ -47,7 +47,7 @@ def _descent_rows(rows):
         for dx, dy in zip(snap.level_dict_x, snap.level_dict_y)
     ]
     bank = ops.leaf_bank_bytes(K, OBJ, W)
-    auto = "prefetch" if bank > ops.FUSED_VMEM_BANK_BYTES else "vmem"
+    auto = ops.pick_fused_variant(K, OBJ, W, compact=False)
 
     legacy_f = DB.descent_bytes(M, widths, W)
     narrow_f = DB.descent_bytes(
@@ -88,7 +88,7 @@ def _descent_rows(rows):
         f"wl_p95={int(np.percentile(wl_leaf, 95))} "
         f"overflow_leaves={overflow}"))
     cbank = ops.compact_leaf_bank_bytes(K, OBJ, Wl)
-    cauto = "prefetch" if cbank > ops.FUSED_VMEM_BANK_BYTES else "vmem"
+    cauto = ops.pick_fused_variant(K, OBJ, Wl, compact=True)
     cvb = DB.verify_bytes(M, T, OBJ, W, K, cauto, compact_words=Wl)
     rows.append(C.row(
         "roofline/descent/verify-compact", 0.0,

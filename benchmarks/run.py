@@ -89,7 +89,11 @@ def main() -> None:
     except ValueError as e:
         sys.exit(str(e))
     out_dir = Path(args.out_dir)
+    from repro.launch.compile_cache import place_compile_cache
+
+    cache_dir = place_compile_cache()
     print("name,us_per_call,derived")
+    print(f"# compile cache: {cache_dir}")
     failures = 0
     for mod_name in selected:
         t0 = time.time()

@@ -14,23 +14,24 @@ Two variants share the rectangle-intersect + keyword-AND predicate:
   encoded).
 * ``frontier_filter_narrow`` -- the bandwidth-lean descent. MBR planes
   arrive as **int16 rank codes** into per-level sorted coordinate
-  dictionaries and are dequantized *inside* the kernel by a VMEM gather,
-  reconstructing the exact f32 coordinates (lossless, so the survivor set
-  is bit-identical to the f32 path -- strictly stronger than the
-  conservative-superset requirement). Bitmaps arrive as **packed word
-  planes**: ops.pack_query_words keeps only each query's nonzero bitmap
-  words (static bucketed width Wp <= W), and the engine gathers just those
-  Wp words per frontier slot, so the biggest descent operand shrinks from
-  ``(M, F, W)`` u32 to ``(M, F, Wp)``.
+  dictionaries and are dequantized to the exact f32 coordinates by the XLA
+  gather that feeds the kernel (lossless, so the survivor set is
+  bit-identical to the f32 path -- strictly stronger than the
+  conservative-superset requirement; the TPU compiler has no in-kernel
+  vector gather). Bitmaps arrive as **packed word planes**:
+  ops.pack_query_words keeps only each query's nonzero bitmap words (static
+  bucketed width Wp <= W), and the engine gathers just those Wp words per
+  frontier slot, so the biggest descent operand shrinks from ``(M, F, W)``
+  u32 to ``(M, F, Wp)``.
 
-Layout notes (TPU): the minor dimension is the frontier width (BF = 128
-lanes by default); the bitmap plane is the big operand. The keyword test is
-one packed word-plane AND followed by a single ``any``-reduction over the
-word axis (popcount-style) per tile -- the reduction tree lives in
-registers, so only the (BM, BF) boolean accumulator is live, same as the
-old static W unroll but without W sliced passes over the tile. The
-coordinate dictionaries are tiny (<= 2n f32 per axis per level) and are
-pinned whole in VMEM across the grid.
+Layout notes (TPU): both variants run one kernel on lane-dense planes --
+the four MBR coordinates as ``(4, M, F)`` planes and the bitmaps word-major
+``(M, W, F)`` -- so the frontier width is the minor (lane) dimension (BF =
+128 lanes by default) and the keyword any-reduction is a sublane max over
+the word axis (``keyword.word_hit``). The transposes ride the XLA gathers
+that build the planes. Validity and survivors cross the kernel boundary as
+int32 (v5e has no int8 vector compare); the wrappers keep the int8
+interface.
 """
 from __future__ import annotations
 
@@ -40,20 +41,53 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .keyword import word_hit
+
 
 def _frontier_kernel(q_rects_ref, q_bm_ref, f_mbrs_ref, f_bm_ref, f_valid_ref, out_ref):
     qr = q_rects_ref[...]  # (BM, 4)
-    fm = f_mbrs_ref[...]  # (BM, BF, 4)
+    fm = f_mbrs_ref[...]  # (4, BM, BF) xlo/ylo/xhi/yhi planes
     inter = (
-        (qr[:, 0:1] <= fm[:, :, 2])
-        & (fm[:, :, 0] <= qr[:, 2:3])
-        & (qr[:, 1:2] <= fm[:, :, 3])
-        & (fm[:, :, 1] <= qr[:, 3:4])
+        (qr[:, 0:1] <= fm[2])
+        & (fm[0] <= qr[:, 2:3])
+        & (qr[:, 1:2] <= fm[3])
+        & (fm[1] <= qr[:, 3:4])
     )  # (BM, BF)
-    qb = q_bm_ref[...]  # (BM, W) uint32
-    fb = f_bm_ref[...]  # (BM, BF, W) uint32
-    kw = jnp.any((fb & qb[:, None, :]) != 0, axis=-1)  # (BM, BF)
-    out_ref[...] = (inter & kw & (f_valid_ref[...] > 0)).astype(jnp.int8)
+    kw = word_hit(f_bm_ref[...], q_bm_ref[...])  # (BM, W, BF) x (BM, W)
+    out_ref[...] = (inter & kw & (f_valid_ref[...] > 0)).astype(jnp.int32)
+
+
+def _frontier_call(q_rects, q_bm, planes, words, f_valid, bm, bf, interpret):
+    """Survivors of ``planes`` (4, M, F) f32 / ``words`` (M, W, F) against
+    the queries; (M, F) int8."""
+    M, W, F = words.shape
+    bm = min(bm, M)
+    bf = min(bf, F)
+    grid = (pl.cdiv(M, bm), pl.cdiv(F, bf))
+    out = pl.pallas_call(
+        _frontier_kernel,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((bm, 4), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, W), lambda i, j: (i, 0)),
+            pl.BlockSpec((4, bm, bf), lambda i, j: (0, i, j)),
+            pl.BlockSpec((bm, W, bf), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((M, F), jnp.int32),
+        interpret=interpret,
+    )(q_rects, q_bm, planes, words, f_valid.astype(jnp.int32))
+    return out.astype(jnp.int8)
+
+
+def dequantize_mbrs(f_codes, dict_x, dict_y):
+    """(4, M, F) exact f32 coordinate planes from (M, F, 4) int16 rank
+    codes and the level's sorted coordinate dictionaries."""
+    fc = f_codes.astype(jnp.int32)
+    return jnp.stack(
+        [dict_x[fc[..., 0]], dict_y[fc[..., 1]], dict_x[fc[..., 2]], dict_y[fc[..., 3]]]
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
@@ -68,45 +102,10 @@ def frontier_filter(
     interpret: bool = False,
 ) -> jax.Array:
     """(M, F) int8 survivor matrix. Inputs padded to tile multiples by ops.py."""
-    M, F = f_valid.shape
-    W = q_bm.shape[1]
-    bm = min(bm, M)
-    bf = min(bf, F)
-    grid = (pl.cdiv(M, bm), pl.cdiv(F, bf))
-    return pl.pallas_call(
-        _frontier_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, bf, 4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf, W), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, F), jnp.int8),
-        interpret=interpret,
-    )(q_rects, q_bm, f_mbrs, f_bm, f_valid)
-
-
-def _frontier_narrow_kernel(
-    q_rects_ref, q_bits_ref, f_codes_ref, f_bm_ref, f_valid_ref, dict_x_ref, dict_y_ref, out_ref
-):
-    qr = q_rects_ref[...]  # (BM, 4) f32 -- queries stay full precision
-    fc = f_codes_ref[...].astype(jnp.int32)  # (BM, BF, 4) int16 rank codes
-    dx = dict_x_ref[...]  # (Dx,) f32 sorted distinct x coords
-    dy = dict_y_ref[...]  # (Dy,) f32 sorted distinct y coords
-    xlo = dx[fc[:, :, 0]]  # exact dequantization: VMEM gather, no rounding
-    ylo = dy[fc[:, :, 1]]
-    xhi = dx[fc[:, :, 2]]
-    yhi = dy[fc[:, :, 3]]
-    inter = (
-        (qr[:, 0:1] <= xhi) & (xlo <= qr[:, 2:3]) & (qr[:, 1:2] <= yhi) & (ylo <= qr[:, 3:4])
-    )  # (BM, BF)
-    qb = q_bits_ref[...]  # (BM, Wp) uint32 packed nonzero query words
-    fb = f_bm_ref[...]  # (BM, BF, Wp) uint32 gathered matching node words
-    kw = jnp.any((fb & qb[:, None, :]) != 0, axis=-1)  # (BM, BF)
-    out_ref[...] = (inter & kw & (f_valid_ref[...] > 0)).astype(jnp.int8)
+    return _frontier_call(
+        q_rects, q_bm, jnp.moveaxis(f_mbrs, -1, 0), jnp.swapaxes(f_bm, 1, 2),
+        f_valid, bm, bf, interpret,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
@@ -123,26 +122,8 @@ def frontier_filter_narrow(
     interpret: bool = False,
 ) -> jax.Array:
     """(M, F) int8 survivor matrix, bit-identical to ``frontier_filter`` on
-    the dequantized planes. Inputs padded to tile multiples by ops.py; the
-    coordinate dictionaries are pinned whole (index map constant 0)."""
-    M, F = f_valid.shape
-    Wp = q_bits.shape[1]
-    bm = min(bm, M)
-    bf = min(bf, F)
-    grid = (pl.cdiv(M, bm), pl.cdiv(F, bf))
-    return pl.pallas_call(
-        _frontier_narrow_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, Wp), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, bf, 4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf, Wp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-            pl.BlockSpec(dict_x.shape, lambda i, j: (0,)),
-            pl.BlockSpec(dict_y.shape, lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, F), jnp.int8),
-        interpret=interpret,
-    )(q_rects, q_bits, f_codes, f_bm, f_valid, dict_x, dict_y)
+    the dequantized planes. Inputs padded to tile multiples by ops.py."""
+    return _frontier_call(
+        q_rects, q_bits, dequantize_mbrs(f_codes, dict_x, dict_y),
+        jnp.swapaxes(f_bm, 1, 2), f_valid, bm, bf, interpret,
+    )

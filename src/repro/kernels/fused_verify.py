@@ -18,21 +18,28 @@ fused/unfused parity suite in tests/test_query_parity.py:
 * ``kwv``  (M, T)     int32 -- per leaf slot, the count of keyword-matching
   valid candidates (the Eq.1 ``verified`` partial sums).
 
-Layout notes (TPU) -- two bank regimes, two kernels:
+Layout notes (TPU) -- two bank regimes, one kernel body. Both variants
+run an ``(M, T, OBJ / BO)`` grid: one (query, leaf slot) pair per step, the
+leaf's objects in tiles of ``BO``. The selected leaf ids and slot flags
+ride in as *scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``);
+there is no in-kernel vector gather, which the TPU compiler does not
+support. Each step reads the leaf's ``(1, BO)`` coordinate/id rows and its
+``(BO, W)`` bitmap rows, tests the words with ``keyword.row_word_hit`` (a
+ones-block contraction on the MXU that also puts the objects on the
+lanes), and writes one lane-dense ``(1, BO)`` id row plus a running
+per-slot count.
 
-* ``fused_verify`` (VMEM variant): the object bank is mapped whole into the
-  kernel (``(K, OBJ)`` / ``(K, OBJ, W)`` blocks, index map pinned to 0) and
-  a static T loop performs in-VMEM row gathers, keeping only one leaf
-  slot's ``(BM, OBJ, W)`` bitmap slab live at a time. Right answer when the
-  bank fits comfortably in VMEM (small-to-medium single-chip indexes).
-* ``fused_verify_prefetch`` (scalar-prefetch variant): the selected leaf-id
-  matrix rides in as a *scalar-prefetch* operand
-  (``pltpu.PrefetchScalarGridSpec``) and drives the bank BlockSpec index
-  maps over a ``(M, T)`` grid, so the pipeline issues exactly one DMA per
-  (query, slot) block -- only the selected ``(1, OBJ)`` / ``(1, OBJ, W)``
-  leaf rows ever enter VMEM. This keeps the fused path (and its
-  one-HBM-pass byte profile) for leaf banks far beyond VMEM, where the
-  VMEM variant cannot compile.
+* ``fused_verify`` (VMEM variant): the object bank is mapped whole into
+  VMEM once (constant index maps, single-buffered) and each step reads the
+  selected leaf's rows in place. Right answer when the bank fits VMEM
+  (small-to-medium single-chip indexes); the kernel's VMEM limit is sized
+  from the bank's padded footprint (``ops.resident_bank_vmem_bytes``).
+* ``fused_verify_prefetch`` (scalar-prefetch variant): the leaf ids drive
+  the bank BlockSpec index maps, so the pipeline DMAs exactly the selected
+  ``(1, BO)`` / ``(BO, W)`` tiles -- only the chosen leaf rows ever enter
+  VMEM. Steps of unselected slots re-address the previous step's block, so
+  the pipeline issues no DMA for them. This keeps the fused path (and its
+  one-HBM-pass byte profile) for leaf banks far beyond VMEM.
 
 Auto-selection lives in ``ops.fused_gather_verify(variant="auto")``, the
 default the engine's ``serve/engine.py::_verify_leaves`` passes through: it
@@ -42,10 +49,6 @@ below the cutoff, the prefetch variant above it -- so the engine never
 falls back to the unfused HBM round-trip on bank-size grounds (only a live
 DeltaBuffer disables fusion). ``variant="vmem"``/``"prefetch"`` force
 either side for A/B rows and the beyond-VMEM oracle sweeps.
-
-The keyword test in both kernels is one packed word-plane AND + a single
-``any``-reduction over the word axis (popcount-style), matching
-skr_verify's restructured inner loop.
 
 Compact-bank twins (DESIGN.md §3.5): ``fused_verify_compact`` /
 ``fused_verify_prefetch_compact`` verify against the leaf-local vocabulary
@@ -68,42 +71,187 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .keyword import row_word_hit
 
-def _fused_verify_kernel(
-    q_rects_ref, q_bm_ref, top_leaf_ref, leaf_ok_ref,
-    ox_ref, oy_ref, obm_ref, oid_ref, ids_ref, kwv_ref,
-):
-    qr = q_rects_ref[...]  # (BM, 4)
-    qb = q_bm_ref[...]  # (BM, W) uint32
-    tl = top_leaf_ref[...]  # (BM, T) int32
-    ok = leaf_ok_ref[...] > 0  # (BM, T)
-    ox = ox_ref[...]  # (K, OBJ) -- VMEM-resident bank
-    oy = oy_ref[...]
-    obm = obm_ref[...]  # (K, OBJ, W)
-    oid = oid_ref[...]
-    K = ox.shape[0]
-    OBJ = ox.shape[1]
-    safe = jnp.clip(tl, 0, K - 1)
-    for t in range(tl.shape[1]):  # static unroll over selected leaf slots
-        leaf = safe[:, t]  # (BM,)
-        cx = ox[leaf]  # (BM, OBJ) in-VMEM gather -- never round-trips HBM
-        cy = oy[leaf]
-        cid = oid[leaf]
+# VMEM the resident variant keeps free beside its bank: double-buffered
+# query/output tiles and the (BO, W) temporaries of the word test
+_RESIDENT_HEADROOM_BYTES = 16 * 1024 * 1024
+
+
+def _pad128(n: int) -> int:
+    return -(-int(n) // 128) * 128
+
+
+def _pad8(n: int) -> int:
+    return -(-int(n) // 8) * 8
+
+
+def resident_bank_vmem_bytes(n_leaves: int, obj_per_leaf: int, n_words: int, n_rows: int) -> int:
+    """VMEM the resident (VMEM-variant) kernel's bank occupies: ``n_rows``
+    (K, OBJ) 32-bit planes plus the (K, OBJ, W) bitmap slab, each padded to
+    the (8, 128) 32-bit tile -- a slab with W < 128 words still fills 128
+    lanes per object."""
+    K, OBJ, W = int(n_leaves), int(obj_per_leaf), int(n_words)
+    rows = n_rows * _pad8(K) * _pad128(OBJ) * 4
+    return rows + K * _pad8(OBJ) * _pad128(W) * 4
+
+
+def _fused_kernel(*refs, resident: bool, bo: int, compact: bool):
+    if compact:
+        (tl_ref, ok_ref, fix_ref, qsig_ref, qr_ref, qw_ref,
+         ox_ref, oy_ref, ow_ref, osig_ref, oid_ref, ids_ref, kwv_ref) = refs
+    else:
+        (tl_ref, ok_ref, fix_ref, qr_ref, qw_ref,
+         ox_ref, oy_ref, ow_ref, oid_ref, ids_ref, kwv_ref) = refs
+    del fix_ref  # index-map only
+    i, t, o = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    ok = ok_ref[i, t] > 0
+
+    @pl.when(o == 0)
+    def _init():
+        kwv_ref[...] = jnp.zeros(kwv_ref.shape, jnp.int32)
+
+    @pl.when(ok)
+    def _verify():
+        if resident:  # whole bank in VMEM: read the leaf's tile in place
+            leaf = tl_ref[i, t]
+            off = pl.multiple_of(o * bo, bo)
+
+            def row(ref):
+                return ref[pl.ds(leaf, 1), pl.ds(off, bo)]
+
+            words = ow_ref[leaf, pl.ds(off, bo), :]  # (BO, W)
+        else:  # the pipeline already DMA'd exactly this leaf's tile
+
+            def row(ref):
+                return ref[...]
+
+            words = ow_ref[...]
+        cx, cy, cid = row(ox_ref), row(oy_ref), row(oid_ref)  # (1, BO)
+        kw = row_word_hit(words, qw_ref[...])  # (1, BO)
+        if compact:  # one-word signature prefilter (implied by the word test)
+            kw = kw & ((row(osig_ref) & qsig_ref[i, t]) != 0)
+        qr = qr_ref[...]  # (1, 4)
         inr = (
             (cx >= qr[:, 0:1])
             & (cx <= qr[:, 2:3])
             & (cy >= qr[:, 1:2])
             & (cy <= qr[:, 3:4])
-        )  # (BM, OBJ)
-        cbm = obm[leaf]  # (BM, OBJ, W): one slot's bitmap slab live at a time
-        kw = jnp.any((cbm & qb[:, None, :]) != 0, axis=-1)  # (BM, OBJ)
-        valid = (cid >= 0) & ok[:, t][:, None]
-        match = inr & kw & valid
-        ids_ref[:, t * OBJ : (t + 1) * OBJ] = jnp.where(match, cid, -1)
-        kwv_ref[:, t] = jnp.sum(kw & valid, axis=1).astype(jnp.int32)
+        )
+        valid = cid >= 0
+        ids_ref[...] = jnp.where(inr & kw & valid, cid, -1)
+        kwv_ref[...] += jnp.sum((kw & valid).astype(jnp.int32), axis=1, keepdims=True)
+
+    @pl.when(jnp.logical_not(ok))
+    def _skip():
+        ids_ref[...] = jnp.full(ids_ref.shape, -1, jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
+def _fused_call(
+    q_rects, q_words, q_sig, top_leaf, leaf_ok, bank, *, resident, bo, interpret
+):
+    """Common implementation of the four fused kernels.
+
+    ``bank`` is ``(obj_x, obj_y, obj_words, obj_id)`` or, compact,
+    ``(obj_x, obj_y, obj_words, obj_sig, obj_id)``; ``q_words`` is (M, W)
+    (one bitmap per query) or, compact, (M, T, Wl) (remapped per slot) with
+    ``q_sig`` (M, T). Returns ``(ids (M, T*OBJ) i32, kwv (M, T) i32)``."""
+    compact = q_sig is not None
+    M, T = top_leaf.shape
+    K, OBJ = bank[0].shape
+    W = bank[2].shape[2]
+    bo = min(bo, OBJ)
+    n_ob = pl.cdiv(OBJ, bo)
+    OBJp = n_ob * bo
+    obj_id = bank[-1]
+    planes = list(bank[:-1])
+    if compact:  # signatures meet the int32 scalar-prefetched query signature
+        planes[3] = jax.lax.bitcast_convert_type(bank[3], jnp.int32)
+    if OBJp != OBJ:
+        pad = OBJp - OBJ
+        planes = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2)) for a in planes]
+        obj_id = jnp.pad(obj_id, [(0, 0), (0, pad)], constant_values=-1)
+    planes.append(obj_id)
+    ox, oy, ow, *rest = planes  # rest: [sig,] id
+
+    safe = jnp.clip(top_leaf.astype(jnp.int32), 0, K - 1)
+    ok = (leaf_ok > 0).astype(jnp.int32)
+    # an unselected slot's steps re-address the block the previous step
+    # used (the last selected slot's final tile), so the pipeline skips
+    # their DMAs; the kernel writes -1 ids for them without reading
+    flat = jnp.arange(M * T, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(ok.reshape(-1) > 0, flat, -1))
+    prev = jnp.where(last >= 0, safe.reshape(-1)[jnp.maximum(last, 0)], 0)
+    tl_eff = jnp.where(ok.reshape(-1) > 0, safe.reshape(-1), prev).reshape(M, T)
+    fix_o = jnp.where(last >= 0, n_ob - 1, 0).reshape(M, T).astype(jnp.int32)
+    prefetch = [tl_eff, ok, fix_o]
+    if compact:
+        prefetch.append(jax.lax.bitcast_convert_type(q_sig, jnp.int32))
+    n_pf = len(prefetch)
+
+    def bank_o(o, i, t, ok_ref, fix_ref):
+        return ok_ref[i, t] * o + (1 - ok_ref[i, t]) * fix_ref[i, t]
+
+    if resident:
+        one = pl.Buffered(1)
+        row_spec = pl.BlockSpec((K, OBJp), lambda i, t, o, *_: (0, 0), pipeline_mode=one)
+        word_spec = pl.BlockSpec(
+            (K, OBJp, W), lambda i, t, o, *_: (0, 0, 0), pipeline_mode=one
+        )
+        n_rows = len(rest) + 2
+        params = pltpu.CompilerParams(
+            vmem_limit_bytes=resident_bank_vmem_bytes(K, OBJp, W, n_rows)
+            + _RESIDENT_HEADROOM_BYTES
+        )
+    else:
+        row_spec = pl.BlockSpec(
+            (None, 1, bo),
+            lambda i, t, o, tl, okr, fix, *_: (tl[i, t], 0, bank_o(o, i, t, okr, fix)),
+        )
+        word_spec = pl.BlockSpec(
+            (None, bo, W),
+            lambda i, t, o, tl, okr, fix, *_: (tl[i, t], bank_o(o, i, t, okr, fix), 0),
+        )
+        ox, oy, *rest = [a.reshape(K, 1, OBJp) for a in (ox, oy, *rest)]
+        params = None
+    if compact:
+        qw = q_words.reshape(M * T, 1, -1)
+        qw_spec = pl.BlockSpec((None, 1, qw.shape[2]), lambda i, t, o, *_: (i * T + t, 0, 0))
+    else:
+        qw = q_words.reshape(M, 1, -1)
+        qw_spec = pl.BlockSpec((None, 1, qw.shape[2]), lambda i, t, o, *_: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_pf,
+        grid=(M, T, n_ob),
+        in_specs=[
+            pl.BlockSpec((None, 1, 4), lambda i, t, o, *_: (i, 0, 0)),
+            qw_spec,
+            row_spec,
+            row_spec,
+            word_spec,
+            *([row_spec] * len(rest)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, 1, bo), lambda i, t, o, *_: (i * T + t, 0, o)),
+            pl.BlockSpec((None, 1, 128), lambda i, t, o, *_: (i * T + t, 0, 0)),
+        ],
+    )
+    kernel = functools.partial(_fused_kernel, resident=resident, bo=bo, compact=compact)
+    ids, kwv = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((M * T, 1, OBJp), jnp.int32),
+            jax.ShapeDtypeStruct((M * T, 1, 128), jnp.int32),
+        ],
+        compiler_params=params,
+        interpret=interpret,
+    )(*prefetch, q_rects.reshape(M, 1, 4), qw, ox, oy, ow, *rest)
+    ids = ids.reshape(M, T, OBJp)[:, :, :OBJ].reshape(M, T * OBJ)
+    return ids, kwv[:, 0, 0].reshape(M, T)
+
+
+@functools.partial(jax.jit, static_argnames=("bo", "interpret"))
 def fused_verify(
     q_rects: jax.Array,  # (M, 4) f32
     q_bm: jax.Array,  # (M, W) u32
@@ -113,66 +261,18 @@ def fused_verify(
     obj_y: jax.Array,  # (K, OBJ) f32
     obj_bm: jax.Array,  # (K, OBJ, W) u32
     obj_id: jax.Array,  # (K, OBJ) int32, -1 pad
-    bm: int = 8,
+    bo: int = 1024,
     interpret: bool = False,
 ):
     """(ids (M, T*OBJ) i32, kwv (M, T) i32): fused gather+verify over the
-    VMEM-resident leaf bank. Query rows padded to tile multiples by ops.py."""
-    M, T = top_leaf.shape
-    K, OBJ = obj_x.shape
-    W = q_bm.shape[1]
-    bm = min(bm, M)
-    grid = (pl.cdiv(M, bm),)
-    return pl.pallas_call(
-        _fused_verify_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 4), lambda i: (i, 0)),
-            pl.BlockSpec((bm, W), lambda i: (i, 0)),
-            pl.BlockSpec((bm, T), lambda i: (i, 0)),
-            pl.BlockSpec((bm, T), lambda i: (i, 0)),
-            pl.BlockSpec((K, OBJ), lambda i: (0, 0)),
-            pl.BlockSpec((K, OBJ), lambda i: (0, 0)),
-            pl.BlockSpec((K, OBJ, W), lambda i: (0, 0, 0)),
-            pl.BlockSpec((K, OBJ), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, T * OBJ), lambda i: (i, 0)),
-            pl.BlockSpec((bm, T), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((M, T * OBJ), jnp.int32),
-            jax.ShapeDtypeStruct((M, T), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q_rects, q_bm, top_leaf, leaf_ok, obj_x, obj_y, obj_bm, obj_id)
+    VMEM-resident leaf bank, ``bo`` objects per grid step."""
+    return _fused_call(
+        q_rects, q_bm, None, top_leaf, leaf_ok, (obj_x, obj_y, obj_bm, obj_id),
+        resident=True, bo=bo, interpret=interpret,
+    )
 
 
-def _fused_prefetch_kernel(
-    tl_ref,  # scalar-prefetch: (M, T) int32 clamped leaf ids
-    q_rects_ref, q_bm_ref, leaf_ok_ref, ox_ref, oy_ref, obm_ref, oid_ref,
-    ids_ref, kwv_ref,
-):
-    qr = q_rects_ref[...]  # (1, 4)
-    qb = q_bm_ref[...]  # (1, W) uint32
-    ok = leaf_ok_ref[...] > 0  # (1, 1)
-    cx = ox_ref[...]  # (1, OBJ) -- the one DMA'd leaf row
-    cy = oy_ref[...]
-    cid = oid_ref[...]
-    inr = (
-        (cx >= qr[:, 0:1])
-        & (cx <= qr[:, 2:3])
-        & (cy >= qr[:, 1:2])
-        & (cy <= qr[:, 3:4])
-    )  # (1, OBJ)
-    kw = jnp.any((obm_ref[...] & qb[:, None, :]) != 0, axis=-1)  # (1, OBJ)
-    valid = (cid >= 0) & ok
-    match = inr & kw & valid
-    ids_ref[...] = jnp.where(match, cid, -1)
-    kwv_ref[...] = jnp.sum(kw & valid, axis=1, keepdims=True).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("bo", "interpret"))
 def fused_verify_prefetch(
     q_rects: jax.Array,  # (M, 4) f32
     q_bm: jax.Array,  # (M, W) u32
@@ -182,88 +282,22 @@ def fused_verify_prefetch(
     obj_y: jax.Array,  # (K, OBJ) f32
     obj_bm: jax.Array,  # (K, OBJ, W) u32
     obj_id: jax.Array,  # (K, OBJ) int32, -1 pad
+    bo: int = 1024,
     interpret: bool = False,
 ):
     """Scalar-prefetched twin of ``fused_verify`` for banks beyond VMEM.
 
-    The clamped leaf-id matrix is the scalar-prefetch operand; the ``(M, T)``
-    grid's bank BlockSpecs index through it, so each grid step DMAs exactly
-    the one ``(1, OBJ)`` / ``(1, OBJ, W)`` leaf row that (query, slot) pair
-    selected. Elementwise-identical outputs to ``fused_verify`` (same clamp +
-    ``leaf_ok``/``cid`` validity semantics)."""
-    M, T = top_leaf.shape
-    K, OBJ = obj_x.shape
-    W = q_bm.shape[1]
-    safe = jnp.clip(top_leaf.astype(jnp.int32), 0, K - 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(M, T),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i, t, tl: (i, 0)),
-            pl.BlockSpec((1, W), lambda i, t, tl: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, t, tl: (i, t)),
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (tl[i, t], 0)),
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (tl[i, t], 0)),
-            pl.BlockSpec((1, OBJ, W), lambda i, t, tl: (tl[i, t], 0, 0)),
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (tl[i, t], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t, tl: (i, t)),
-        ],
+    The clamped leaf-id matrix drives the bank BlockSpecs, so each grid
+    step DMAs exactly the ``(1, BO)`` / ``(BO, W)`` tile of the leaf that
+    (query, slot) pair selected. Elementwise-identical outputs to
+    ``fused_verify`` (same clamp + ``leaf_ok``/``cid`` validity semantics)."""
+    return _fused_call(
+        q_rects, q_bm, None, top_leaf, leaf_ok, (obj_x, obj_y, obj_bm, obj_id),
+        resident=False, bo=bo, interpret=interpret,
     )
-    return pl.pallas_call(
-        _fused_prefetch_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((M, T * OBJ), jnp.int32),
-            jax.ShapeDtypeStruct((M, T), jnp.int32),
-        ],
-        interpret=interpret,
-    )(safe, q_rects, q_bm, leaf_ok, obj_x, obj_y, obj_bm, obj_id)
 
 
-# ---------------------------------------------------- compact-bank twins
-def _fused_verify_compact_kernel(
-    q_rects_ref, q_cbm_ref, q_sig_ref, top_leaf_ref, leaf_ok_ref,
-    ox_ref, oy_ref, ocbm_ref, osig_ref, oid_ref, ids_ref, kwv_ref,
-):
-    qr = q_rects_ref[...]  # (BM, 4)
-    qc = q_cbm_ref[...]  # (BM, T, Wl) uint32 -- leaf-local query words
-    qs = q_sig_ref[...]  # (BM, T) uint32 -- OR-fold per (query, slot)
-    tl = top_leaf_ref[...]  # (BM, T) int32
-    ok = leaf_ok_ref[...] > 0  # (BM, T)
-    ox = ox_ref[...]  # (K, OBJ) -- VMEM-resident compact bank
-    oy = oy_ref[...]
-    ocbm = ocbm_ref[...]  # (K, OBJ, Wl)
-    osig = osig_ref[...]  # (K, OBJ)
-    oid = oid_ref[...]
-    K = ox.shape[0]
-    OBJ = ox.shape[1]
-    safe = jnp.clip(tl, 0, K - 1)
-    for t in range(tl.shape[1]):  # static unroll over selected leaf slots
-        leaf = safe[:, t]  # (BM,)
-        cx = ox[leaf]  # (BM, OBJ)
-        cy = oy[leaf]
-        cid = oid[leaf]
-        inr = (
-            (cx >= qr[:, 0:1])
-            & (cx <= qr[:, 2:3])
-            & (cy >= qr[:, 1:2])
-            & (cy <= qr[:, 3:4])
-        )  # (BM, OBJ)
-        # one-word signature prefilter, then the Wl-word any-reduction;
-        # the sig test is implied by the word test, so kw is unchanged
-        sig_hit = (osig[leaf] & qs[:, t][:, None]) != 0  # (BM, OBJ)
-        cbm = ocbm[leaf]  # (BM, OBJ, Wl)
-        kw = sig_hit & jnp.any((cbm & qc[:, t][:, None, :]) != 0, axis=-1)
-        valid = (cid >= 0) & ok[:, t][:, None]
-        match = inr & kw & valid
-        ids_ref[:, t * OBJ : (t + 1) * OBJ] = jnp.where(match, cid, -1)
-        kwv_ref[:, t] = jnp.sum(kw & valid, axis=1).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bo", "interpret"))
 def fused_verify_compact(
     q_rects: jax.Array,  # (M, 4) f32
     q_cbm: jax.Array,  # (M, T, Wl) u32 leaf-local remapped query words
@@ -275,73 +309,20 @@ def fused_verify_compact(
     obj_cbm: jax.Array,  # (K, OBJ, Wl) u32 compact bitmap slab
     obj_sig: jax.Array,  # (K, OBJ) u32 OR-fold signatures
     obj_id: jax.Array,  # (K, OBJ) int32, -1 pad
-    bm: int = 8,
+    bo: int = 1024,
     interpret: bool = False,
 ):
     """Compact-bank twin of ``fused_verify``: identical (ids, kwv) outputs,
     but the bitmap slab is ``Wl`` leaf-local words + a one-word signature
-    instead of ``W`` global words. Query rows pre-padded by ops.py."""
-    M, T = top_leaf.shape
-    K, OBJ = obj_x.shape
-    Wl = q_cbm.shape[2]
-    bm = min(bm, M)
-    grid = (pl.cdiv(M, bm),)
-    return pl.pallas_call(
-        _fused_verify_compact_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 4), lambda i: (i, 0)),
-            pl.BlockSpec((bm, T, Wl), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bm, T), lambda i: (i, 0)),
-            pl.BlockSpec((bm, T), lambda i: (i, 0)),
-            pl.BlockSpec((bm, T), lambda i: (i, 0)),
-            pl.BlockSpec((K, OBJ), lambda i: (0, 0)),
-            pl.BlockSpec((K, OBJ), lambda i: (0, 0)),
-            pl.BlockSpec((K, OBJ, Wl), lambda i: (0, 0, 0)),
-            pl.BlockSpec((K, OBJ), lambda i: (0, 0)),
-            pl.BlockSpec((K, OBJ), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bm, T * OBJ), lambda i: (i, 0)),
-            pl.BlockSpec((bm, T), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((M, T * OBJ), jnp.int32),
-            jax.ShapeDtypeStruct((M, T), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q_rects, q_cbm, q_sig, top_leaf, leaf_ok, obj_x, obj_y, obj_cbm,
-      obj_sig, obj_id)
+    instead of ``W`` global words."""
+    return _fused_call(
+        q_rects, q_cbm, q_sig, top_leaf, leaf_ok,
+        (obj_x, obj_y, obj_cbm, obj_sig, obj_id),
+        resident=True, bo=bo, interpret=interpret,
+    )
 
 
-def _fused_prefetch_compact_kernel(
-    tl_ref,  # scalar-prefetch: (M, T) int32 clamped leaf ids
-    q_rects_ref, q_cbm_ref, q_sig_ref, leaf_ok_ref,
-    ox_ref, oy_ref, ocbm_ref, osig_ref, oid_ref,
-    ids_ref, kwv_ref,
-):
-    qr = q_rects_ref[...]  # (1, 4)
-    qc = q_cbm_ref[...]  # (1, 1, Wl) uint32
-    qs = q_sig_ref[...]  # (1, 1) uint32
-    ok = leaf_ok_ref[...] > 0  # (1, 1)
-    cx = ox_ref[...]  # (1, OBJ) -- the one DMA'd leaf row
-    cy = oy_ref[...]
-    cid = oid_ref[...]
-    inr = (
-        (cx >= qr[:, 0:1])
-        & (cx <= qr[:, 2:3])
-        & (cy >= qr[:, 1:2])
-        & (cy <= qr[:, 3:4])
-    )  # (1, OBJ)
-    sig_hit = (osig_ref[...] & qs) != 0  # (1, OBJ)
-    kw = sig_hit & jnp.any((ocbm_ref[...] & qc[:, 0][:, None, :]) != 0, axis=-1)
-    valid = (cid >= 0) & ok
-    match = inr & kw & valid
-    ids_ref[...] = jnp.where(match, cid, -1)
-    kwv_ref[...] = jnp.sum(kw & valid, axis=1, keepdims=True).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("bo", "interpret"))
 def fused_verify_prefetch_compact(
     q_rects: jax.Array,  # (M, 4) f32
     q_cbm: jax.Array,  # (M, T, Wl) u32 leaf-local remapped query words
@@ -353,41 +334,14 @@ def fused_verify_prefetch_compact(
     obj_cbm: jax.Array,  # (K, OBJ, Wl) u32
     obj_sig: jax.Array,  # (K, OBJ) u32
     obj_id: jax.Array,  # (K, OBJ) int32, -1 pad
+    bo: int = 1024,
     interpret: bool = False,
 ):
-    """Compact-bank twin of ``fused_verify_prefetch``: one DMA per
-    (query, slot) block over the ``(M, T)`` grid, with the per-slot
-    remapped query words riding the same grid."""
-    M, T = top_leaf.shape
-    K, OBJ = obj_x.shape
-    Wl = q_cbm.shape[2]
-    safe = jnp.clip(top_leaf.astype(jnp.int32), 0, K - 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(M, T),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda i, t, tl: (i, 0)),
-            pl.BlockSpec((1, 1, Wl), lambda i, t, tl: (i, t, 0)),
-            pl.BlockSpec((1, 1), lambda i, t, tl: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t, tl: (i, t)),
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (tl[i, t], 0)),
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (tl[i, t], 0)),
-            pl.BlockSpec((1, OBJ, Wl), lambda i, t, tl: (tl[i, t], 0, 0)),
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (tl[i, t], 0)),
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (tl[i, t], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, OBJ), lambda i, t, tl: (i, t)),
-            pl.BlockSpec((1, 1), lambda i, t, tl: (i, t)),
-        ],
+    """Compact-bank twin of ``fused_verify_prefetch``: one DMA per selected
+    (query, slot, object tile), with the per-slot remapped query words
+    riding the same grid."""
+    return _fused_call(
+        q_rects, q_cbm, q_sig, top_leaf, leaf_ok,
+        (obj_x, obj_y, obj_cbm, obj_sig, obj_id),
+        resident=False, bo=bo, interpret=interpret,
     )
-    return pl.pallas_call(
-        _fused_prefetch_compact_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((M, T * OBJ), jnp.int32),
-            jax.ShapeDtypeStruct((M, T), jnp.int32),
-        ],
-        interpret=interpret,
-    )(safe, q_rects, q_cbm, q_sig, leaf_ok, obj_x, obj_y, obj_cbm,
-      obj_sig, obj_id)

@@ -12,16 +12,17 @@ bound" sentinel, mirroring the NEVER_RECT padding of the range path.
 Like the range path, two variants share the predicate: ``knn_filter`` on
 full-width f32/uint32 planes (A/B baseline and delta-augmented fallback)
 and ``knn_filter_narrow`` on int16 rank-coded MBR planes + packed word
-planes. The narrow kernel dequantizes the codes to exact f32 via a VMEM
-dictionary gather before the distance computation, so the emitted distances
-are bit-identical to the f32 kernel's -- the bound-tightening descent and
-top-k merges see the same numbers on either path.
+planes. The narrow variant dequantizes the codes to exact f32 in the XLA
+gather that feeds the kernel (``frontier.dequantize_mbrs``), so both
+variants run the same kernel and the emitted distances are bit-identical
+-- the bound-tightening descent and top-k merges see the same numbers on
+either path.
 
-Layout notes (TPU): identical tiling to ``frontier_filter`` -- the minor
-dimension is the frontier width (BF = 128 lanes by default). The keyword
-test is one packed word-plane AND + a single ``any``-reduction over the
-word axis per tile (popcount-style); only the (BM, BF) distance/keyword
-accumulators stay live.
+Layout notes (TPU): identical tiling to ``frontier_filter`` -- lane-dense
+``(4, M, F)`` coordinate planes and word-major ``(M, W, F)`` bitmaps, the
+frontier width on the lanes (BF = 128 by default), the keyword test a
+sublane max over the word axis (``keyword.word_hit``); only the (BM, BF)
+distance/keyword accumulators stay live.
 """
 from __future__ import annotations
 
@@ -30,6 +31,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .frontier import dequantize_mbrs
+from .keyword import word_hit
 
 
 def _mbr_sq_dist(px, py, xlo, ylo, xhi, yhi):
@@ -41,13 +45,34 @@ def _mbr_sq_dist(px, py, xlo, ylo, xhi, yhi):
 
 def _knn_kernel(q_pts_ref, q_bm_ref, f_mbrs_ref, f_bm_ref, f_valid_ref, out_ref):
     qp = q_pts_ref[...]  # (BM, 2)
-    fm = f_mbrs_ref[...]  # (BM, BF, 4)
-    d2 = _mbr_sq_dist(qp[:, 0:1], qp[:, 1:2], fm[:, :, 0], fm[:, :, 1], fm[:, :, 2], fm[:, :, 3])
-    qb = q_bm_ref[...]  # (BM, W) uint32
-    fb = f_bm_ref[...]  # (BM, BF, W) uint32
-    kw = jnp.any((fb & qb[:, None, :]) != 0, axis=-1)  # (BM, BF)
+    fm = f_mbrs_ref[...]  # (4, BM, BF) xlo/ylo/xhi/yhi planes
+    d2 = _mbr_sq_dist(qp[:, 0:1], qp[:, 1:2], fm[0], fm[1], fm[2], fm[3])
+    kw = word_hit(f_bm_ref[...], q_bm_ref[...])  # (BM, W, BF) x (BM, W)
     ok = kw & (f_valid_ref[...] > 0)
     out_ref[...] = jnp.where(ok, d2, jnp.inf).astype(jnp.float32)
+
+
+def _knn_call(q_pts, q_bm, planes, words, f_valid, bm, bf, interpret):
+    """Distances of ``planes`` (4, M, F) f32 / ``words`` (M, W, F) to the
+    query points; (M, F) f32."""
+    M, W, F = words.shape
+    bm = min(bm, M)
+    bf = min(bf, F)
+    grid = (pl.cdiv(M, bm), pl.cdiv(F, bf))
+    return pl.pallas_call(
+        _knn_kernel,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((bm, 2), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, W), lambda i, j: (i, 0)),
+            pl.BlockSpec((4, bm, bf), lambda i, j: (0, i, j)),
+            pl.BlockSpec((bm, W, bf), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
+        interpret=interpret,
+    )(q_pts, q_bm, planes, words, f_valid.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
@@ -63,42 +88,10 @@ def knn_filter(
 ) -> jax.Array:
     """(M, F) f32 squared MBR min-distances (+inf where the slot is invalid
     or shares no keyword bit). Inputs padded to tile multiples by ops.py."""
-    M, F = f_valid.shape
-    W = q_bm.shape[1]
-    bm = min(bm, M)
-    bf = min(bf, F)
-    grid = (pl.cdiv(M, bm), pl.cdiv(F, bf))
-    return pl.pallas_call(
-        _knn_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, bf, 4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf, W), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
-        interpret=interpret,
-    )(q_pts, q_bm, f_mbrs, f_bm, f_valid)
-
-
-def _knn_narrow_kernel(
-    q_pts_ref, q_bits_ref, f_codes_ref, f_bm_ref, f_valid_ref, dict_x_ref, dict_y_ref, out_ref
-):
-    qp = q_pts_ref[...]  # (BM, 2) f32
-    fc = f_codes_ref[...].astype(jnp.int32)  # (BM, BF, 4) int16 rank codes
-    dx = dict_x_ref[...]  # (Dx,) f32
-    dy = dict_y_ref[...]  # (Dy,) f32
-    d2 = _mbr_sq_dist(
-        qp[:, 0:1], qp[:, 1:2], dx[fc[:, :, 0]], dy[fc[:, :, 1]], dx[fc[:, :, 2]], dy[fc[:, :, 3]]
+    return _knn_call(
+        q_pts, q_bm, jnp.moveaxis(f_mbrs, -1, 0), jnp.swapaxes(f_bm, 1, 2),
+        f_valid, bm, bf, interpret,
     )
-    qb = q_bits_ref[...]  # (BM, Wp) uint32 packed query words
-    fb = f_bm_ref[...]  # (BM, BF, Wp) uint32
-    kw = jnp.any((fb & qb[:, None, :]) != 0, axis=-1)
-    ok = kw & (f_valid_ref[...] > 0)
-    out_ref[...] = jnp.where(ok, d2, jnp.inf).astype(jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
@@ -116,24 +109,7 @@ def knn_filter_narrow(
 ) -> jax.Array:
     """(M, F) f32 squared MBR min-distances, bit-identical to ``knn_filter``
     on the dequantized planes (+inf sentinel semantics unchanged)."""
-    M, F = f_valid.shape
-    Wp = q_bits.shape[1]
-    bm = min(bm, M)
-    bf = min(bf, F)
-    grid = (pl.cdiv(M, bm), pl.cdiv(F, bf))
-    return pl.pallas_call(
-        _knn_narrow_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, Wp), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, bf, 4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf, Wp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-            pl.BlockSpec(dict_x.shape, lambda i, j: (0,)),
-            pl.BlockSpec(dict_y.shape, lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
-        interpret=interpret,
-    )(q_pts, q_bits, f_codes, f_bm, f_valid, dict_x, dict_y)
+    return _knn_call(
+        q_pts, q_bits, dequantize_mbrs(f_codes, dict_x, dict_y),
+        jnp.swapaxes(f_bm, 1, 2), f_valid, bm, bf, interpret,
+    )

@@ -1,14 +1,25 @@
 """Jitted public wrappers around the Pallas kernels.
 
-The wrappers pad inputs to tile multiples, pick ``interpret=True`` on CPU
-(the container target; kernels execute their Python bodies for validation)
-and compiled Mosaic on TPU, and slice outputs back. They are the only entry
-points the rest of the framework uses.
+The wrappers pad inputs to tile multiples, run each kernel, and slice
+outputs back. They are the only entry points the rest of the framework
+uses. Where a kernel runs is decided here and nowhere else, by the JAX
+backend alone (``_interpret``):
+
+* TPU -- every kernel compiles to Mosaic. The serving main path (frontier
+  and kNN filters, narrow and full width; the fused verify kernels, VMEM
+  and prefetch, full width and compact; the candidate verify kernels; the
+  subscription matcher) compiles for v5e at serving widths
+  (tests/test_tpu_compile.py) and runs there (``chip_smoke.py``). No
+  wrapper falls back to interpret mode or to its ``ref.py`` twin on a TPU.
+* CPU -- every kernel runs in Pallas interpret mode (its Python body under
+  XLA:CPU), which is how the test suite checks it against ``ref.py``.
+
+``skr_filter`` (reached only by the A/B ``mode="dense"`` descent) and
+``cdf_mlp_bank`` (no serving caller) are not compiled for TPU.
 """
 from __future__ import annotations
 
-import functools
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +32,7 @@ from .fused_verify import (
     fused_verify_compact,
     fused_verify_prefetch,
     fused_verify_prefetch_compact,
+    resident_bank_vmem_bytes,
 )
 from .knn_filter import knn_filter, knn_filter_narrow
 from .skr_filter import skr_filter
@@ -29,7 +41,8 @@ from .sub_match import sub_match
 from . import ref
 
 
-def _on_cpu() -> bool:
+def _interpret() -> bool:
+    """Interpret mode on the CPU backend, compiled Mosaic everywhere else."""
     return jax.default_backend() == "cpu"
 
 
@@ -37,10 +50,13 @@ def _on_cpu() -> bool:
 # (xlo > xhi): used for node/query padding here and in serve.plan
 NEVER_RECT = (2.0, 2.0, -2.0, -2.0)
 
-# Leaf-bank byte budget above which the engine routes fused verification to
-# the scalar-prefetched kernel instead of mapping the bank whole into VMEM.
-# Half of a ~16 MiB per-core VMEM: leaves headroom for the query tiles, the
-# per-slot bitmap slab, and the output blocks. serve.engine._verify_leaves
+# VMEM budget for the resident (VMEM-variant) fused-verify leaf bank, in
+# padded VMEM bytes (``resident_bank_vmem_bytes``): above it the engine
+# routes fused verification to the scalar-prefetched kernel. A v5e core has
+# 128 MiB of VMEM and a 16 MiB default scoped limit; the VMEM kernel sets
+# its own limit to its bank footprint plus 16 MiB of headroom, and at a
+# bank just under this cutoff it compiles for v5e at W = 256 and at the
+# compact Wl (tests/test_tpu_compile.py). serve.engine._verify_leaves
 # applies the rule; fused_gather_verify(variant=...) overrides it.
 FUSED_VMEM_BANK_BYTES = 8 * 1024 * 1024
 
@@ -162,11 +178,9 @@ def _pad_dim(a: jax.Array, axis: int, mult: int, fill=0) -> jax.Array:
 
 
 def filter_pairs(
-    q_rects, q_bm, n_mbrs, n_bm, bm: int = 128, bk: int = 128, interpret: Optional[bool] = None
+    q_rects, q_bm, n_mbrs, n_bm, bm: int = 128, bk: int = 128
 ) -> jax.Array:
     """(M, K) int8 relevance via the Pallas filter kernel (padded + sliced)."""
-    if interpret is None:
-        interpret = _on_cpu()
     M, K = q_rects.shape[0], n_mbrs.shape[0]
     bm_ = min(bm, max(M, 1))
     bk_ = min(bk, max(K, 1))
@@ -178,13 +192,13 @@ def filter_pairs(
     if pad_k:
         nm = jnp.concatenate([nm, jnp.tile(jnp.array([NEVER_RECT], jnp.float32), (pad_k, 1))], 0)
     nb = _pad_dim(jnp.asarray(n_bm, jnp.uint32), 0, bk_)
-    out = skr_filter(qr, qb, nm, nb, bm=bm_, bk=bk_, interpret=interpret)
+    out = skr_filter(qr, qb, nm, nb, bm=bm_, bk=bk_, interpret=_interpret())
     return out[:M, :K]
 
 
 def match_subscriptions(
     obj_pts, obj_bm, sub_rects, sub_bm, sub_sig=None,
-    bn: int = 8, bs: int = 128, interpret: Optional[bool] = None,
+    bn: int = 8, bs: int = 128,
 ) -> jax.Array:
     """(N, S) int8 continuous-filter match matrix via the Pallas sub_match
     kernel (padded + sliced; DESIGN.md §8).
@@ -197,8 +211,6 @@ def match_subscriptions(
     bitmap and subscription padding a zero bitmap + NEVER_RECT, so padded
     slots can never match.
     """
-    if interpret is None:
-        interpret = _on_cpu()
     obj_pts = np.asarray(obj_pts, np.float32).reshape(-1, 2)
     obj_bm = np.asarray(obj_bm, np.uint32)
     N, S = obj_pts.shape[0], np.asarray(sub_rects).shape[0]
@@ -223,17 +235,14 @@ def match_subscriptions(
         )
     sb = _pad_dim(jnp.asarray(sub_bm, jnp.uint32), 0, bs_)
     ssg = _pad_dim(jnp.asarray(s_sig, jnp.uint32), 0, bs_)
-    out = sub_match(op, ow, ob, osg, sr, sb, ssg, bn=bn_, bs=bs_, interpret=interpret)
+    out = sub_match(op, ow, ob, osg, sr, sb, ssg, bn=bn_, bs=bs_, interpret=_interpret())
     return out[:N, :S]
 
 
 def filter_frontier(
     q_rects, q_bm, f_mbrs, f_bm, f_valid, bm: int = 8, bf: int = 128,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(M, F) int8 frontier-survivor matrix via the Pallas frontier kernel."""
-    if interpret is None:
-        interpret = _on_cpu()
     M, F = f_valid.shape
     bm_ = min(bm, max(M, 1))
     bf_ = min(bf, max(F, 1))
@@ -242,21 +251,20 @@ def filter_frontier(
     fm = _pad_dim(_pad_dim(jnp.asarray(f_mbrs, jnp.float32), 0, bm_), 1, bf_)
     fb = _pad_dim(_pad_dim(jnp.asarray(f_bm, jnp.uint32), 0, bm_), 1, bf_)
     fv = _pad_dim(_pad_dim(jnp.asarray(f_valid, jnp.int8), 0, bm_), 1, bf_)
-    out = frontier_filter(qr, qb, fm, fb, fv, bm=bm_, bf=bf_, interpret=interpret)
+    out = frontier_filter(qr, qb, fm, fb, fv, bm=bm_, bf=bf_, interpret=_interpret())
     return out[:M, :F]
 
 
 def filter_frontier_narrow(
     q_rects, q_bits, f_codes, f_bm, f_valid, dict_x, dict_y,
-    bm: int = 8, bf: int = 128, interpret: Optional[bool] = None,
+    bm: int = 8, bf: int = 128,
 ) -> jax.Array:
     """(M, F) int8 frontier-survivor matrix on the bandwidth-lean planes:
-    int16 MBR rank codes (dequantized in-kernel through the per-level
-    coordinate dictionaries -- exact) and packed nonzero word planes from
+    int16 MBR rank codes (dequantized through the per-level coordinate
+    dictionaries by the gather feeding the kernel -- exact) and packed
+    nonzero word planes from
     ``pack_query_words``. Bit-identical survivors to ``filter_frontier`` on
     the corresponding f32/full-width operands."""
-    if interpret is None:
-        interpret = _on_cpu()
     M, F = f_valid.shape
     bm_ = min(bm, max(M, 1))
     bf_ = min(bf, max(F, 1))
@@ -268,19 +276,16 @@ def filter_frontier_narrow(
     out = frontier_filter_narrow(
         qr, qb, fc, fb, fv,
         jnp.asarray(dict_x, jnp.float32), jnp.asarray(dict_y, jnp.float32),
-        bm=bm_, bf=bf_, interpret=interpret,
+        bm=bm_, bf=bf_, interpret=_interpret(),
     )
     return out[:M, :F]
 
 
 def knn_frontier_dist(
     q_pts, q_bm, f_mbrs, f_bm, f_valid, bm: int = 8, bf: int = 128,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(M, F) f32 squared frontier MBR min-distances via the Pallas kNN kernel
     (+inf at invalid / keyword-miss slots, including the padding added here)."""
-    if interpret is None:
-        interpret = _on_cpu()
     M, F = f_valid.shape
     bm_ = min(bm, max(M, 1))
     bf_ = min(bf, max(F, 1))
@@ -289,19 +294,17 @@ def knn_frontier_dist(
     fm = _pad_dim(_pad_dim(jnp.asarray(f_mbrs, jnp.float32), 0, bm_), 1, bf_)
     fb = _pad_dim(_pad_dim(jnp.asarray(f_bm, jnp.uint32), 0, bm_), 1, bf_)
     fv = _pad_dim(_pad_dim(jnp.asarray(f_valid, jnp.int8), 0, bm_), 1, bf_)
-    out = knn_filter(qp, qb, fm, fb, fv, bm=bm_, bf=bf_, interpret=interpret)
+    out = knn_filter(qp, qb, fm, fb, fv, bm=bm_, bf=bf_, interpret=_interpret())
     return out[:M, :F]
 
 
 def knn_frontier_dist_narrow(
     q_pts, q_bits, f_codes, f_bm, f_valid, dict_x, dict_y,
-    bm: int = 8, bf: int = 128, interpret: Optional[bool] = None,
+    bm: int = 8, bf: int = 128,
 ) -> jax.Array:
     """(M, F) f32 squared frontier MBR min-distances on the bandwidth-lean
     planes (int16 rank codes + packed word planes); bit-identical distances
     to ``knn_frontier_dist`` on the corresponding f32/full-width operands."""
-    if interpret is None:
-        interpret = _on_cpu()
     M, F = f_valid.shape
     bm_ = min(bm, max(M, 1))
     bf_ = min(bf, max(F, 1))
@@ -313,18 +316,15 @@ def knn_frontier_dist_narrow(
     out = knn_filter_narrow(
         qp, qb, fc, fb, fv,
         jnp.asarray(dict_x, jnp.float32), jnp.asarray(dict_y, jnp.float32),
-        bm=bm_, bf=bf_, interpret=interpret,
+        bm=bm_, bf=bf_, interpret=_interpret(),
     )
     return out[:M, :F]
 
 
 def verify_candidates(
     q_rects, q_bm, cand_x, cand_y, cand_bm, cand_valid, bm: int = 8, bc: int = 512,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(M, C) int8 verified-candidate matrix via the Pallas verify kernel."""
-    if interpret is None:
-        interpret = _on_cpu()
     M, C = cand_x.shape
     bm_ = min(bm, max(M, 1))
     bc_ = min(bc, max(C, 1))
@@ -334,21 +334,19 @@ def verify_candidates(
     cy = _pad_dim(_pad_dim(jnp.asarray(cand_y, jnp.float32), 0, bm_), 1, bc_)
     cb = _pad_dim(_pad_dim(jnp.asarray(cand_bm, jnp.uint32), 0, bm_), 1, bc_)
     cv = _pad_dim(_pad_dim(jnp.asarray(cand_valid, jnp.int8), 0, bm_), 1, bc_)
-    out = skr_verify(qr, qb, cx, cy, cb, cv, bm=bm_, bc=bc_, interpret=interpret)
+    out = skr_verify(qr, qb, cx, cy, cb, cv, bm=bm_, bc=bc_, interpret=_interpret())
     return out[:M, :C]
 
 
 def verify_candidates_compact(
     q_rects, q_cbm, q_sig, cand_x, cand_y, cand_cbm, cand_sig, cand_valid,
-    bm: int = 8, interpret: Optional[bool] = None,
+    bm: int = 8,
 ) -> jax.Array:
     """(M, T*OBJ) int8 verified-candidate matrix on the compact leaf
     vocabulary (DESIGN.md §3.5). Candidates must be leaf-slot-major (T
     slots of OBJ objects) because the remapped query words differ per slot;
-    the slot axis is the kernel grid, so no candidate-axis padding is
-    needed."""
-    if interpret is None:
-        interpret = _on_cpu()
+    the kernel runs slot-major and tiles each slot's objects itself, so
+    only the query rows are padded here."""
     M = cand_x.shape[0]
     bm_ = min(bm, max(M, 1))
     qr = _pad_dim(jnp.asarray(q_rects, jnp.float32), 0, bm_)
@@ -360,14 +358,30 @@ def verify_candidates_compact(
     cs = _pad_dim(jnp.asarray(cand_sig, jnp.uint32), 0, bm_)
     cv = _pad_dim(jnp.asarray(cand_valid, jnp.int8), 0, bm_)
     out = skr_verify_compact(
-        qr, qc, qs, cx, cy, cb, cs, cv, bm=bm_, interpret=interpret
+        qr, qc, qs, cx, cy, cb, cs, cv, bm=bm_, interpret=_interpret()
     )
     return out[:M]
 
 
+def pick_fused_variant(
+    K: int, OBJ: int, n_words: int, compact: bool, variant: str = "auto"
+) -> str:
+    """The fused-verify kernel ``variant`` resolves to for a (K, OBJ) leaf
+    bank of ``n_words`` bitmap words (``compact``: the leaf-local bank,
+    which adds a signature plane): ``"auto"`` is ``"vmem"`` while the
+    bank's padded VMEM footprint stays within ``FUSED_VMEM_BANK_BYTES``."""
+    if variant not in ("auto", "vmem", "prefetch"):
+        raise ValueError(f"unknown fused-verify variant: {variant!r}")
+    if variant != "auto":
+        return variant
+    rows = 4 if compact else 3
+    big = resident_bank_vmem_bytes(K, OBJ, n_words, rows) > FUSED_VMEM_BANK_BYTES
+    return "prefetch" if big else "vmem"
+
+
 def fused_gather_verify(
     q_rects, q_bm, top_leaf, leaf_ok, obj_x, obj_y, obj_bm, obj_id,
-    bm: int = 8, interpret: Optional[bool] = None, variant: str = "auto",
+    bo: int = 1024, variant: str = "auto",
 ):
     """Fused leaf gather + verify via the Pallas fused kernels (DESIGN.md §3.5).
 
@@ -379,100 +393,72 @@ def fused_gather_verify(
     bit-identical to the unfused gather -> ``verify_candidates`` ordering)
     and kwv (M, T) i32 per-slot Eq.1 ``verified`` partial counts.
 
-    ``variant`` picks the kernel: ``"vmem"`` maps the bank whole into VMEM
-    (static-T in-VMEM gathers), ``"prefetch"`` uses the scalar-prefetched
-    (M, T) leaf-id grid that DMAs one leaf row per (query, slot) block and
-    keeps fusion for banks beyond VMEM, ``"auto"`` compares the bank bytes
-    against ``FUSED_VMEM_BANK_BYTES``. Both variants are elementwise
-    identical (tests/test_kernels.py).
+    ``variant`` picks the kernel: ``"vmem"`` maps the bank whole into VMEM,
+    ``"prefetch"`` uses the scalar-prefetched leaf ids to DMA only the
+    selected leaf tiles and keeps fusion for banks beyond VMEM, ``"auto"``
+    compares the bank's padded VMEM footprint
+    (``resident_bank_vmem_bytes``) against ``FUSED_VMEM_BANK_BYTES``. Both
+    variants are elementwise identical (tests/test_kernels.py). ``bo`` is
+    the object tile per grid step.
     """
-    if interpret is None:
-        interpret = _on_cpu()
-    if variant not in ("auto", "vmem", "prefetch"):
-        raise ValueError(f"unknown fused-verify variant: {variant!r}")
-    if variant == "auto":
-        K, OBJ = obj_x.shape
-        W = q_bm.shape[1]
-        big = leaf_bank_bytes(K, OBJ, W) > FUSED_VMEM_BANK_BYTES
-        variant = "prefetch" if big else "vmem"
-    M = q_rects.shape[0]
-    bm_ = min(bm, max(M, 1))
-    qr = _pad_dim(jnp.asarray(q_rects, jnp.float32), 0, bm_)
-    qb = _pad_dim(jnp.asarray(q_bm, jnp.uint32), 0, bm_)
-    tl = _pad_dim(jnp.asarray(top_leaf, jnp.int32), 0, bm_)
-    ok = _pad_dim(jnp.asarray(leaf_ok, jnp.int8), 0, bm_)
-    bank = (
+    K, OBJ = obj_x.shape
+    kernel = (
+        fused_verify_prefetch
+        if pick_fused_variant(K, OBJ, obj_bm.shape[2], False, variant) == "prefetch"
+        else fused_verify
+    )
+    return kernel(
+        jnp.asarray(q_rects, jnp.float32), jnp.asarray(q_bm, jnp.uint32),
+        jnp.asarray(top_leaf, jnp.int32), jnp.asarray(leaf_ok, jnp.int8),
         jnp.asarray(obj_x, jnp.float32), jnp.asarray(obj_y, jnp.float32),
         jnp.asarray(obj_bm, jnp.uint32), jnp.asarray(obj_id, jnp.int32),
+        bo=bo, interpret=_interpret(),
     )
-    if variant == "prefetch":
-        ids, kwv = fused_verify_prefetch(qr, qb, tl, ok, *bank, interpret=interpret)
-    else:
-        ids, kwv = fused_verify(qr, qb, tl, ok, *bank, bm=bm_, interpret=interpret)
-    return ids[:M], kwv[:M]
 
 
 def fused_gather_verify_compact(
     q_rects, q_cbm, q_sig, top_leaf, leaf_ok,
     obj_x, obj_y, obj_cbm, obj_sig, obj_id,
-    bm: int = 8, interpret: Optional[bool] = None, variant: str = "auto",
+    bo: int = 1024, variant: str = "auto",
 ):
     """Compact-bank sibling of ``fused_gather_verify`` (DESIGN.md §3.5).
 
     Takes the per-slot remapped query words from ``remap_query_words``
     instead of the global bitmap, and the snapshot's compact leaf bank
     (``leaf_obj_cbm``/``leaf_obj_sig``). ``variant="auto"`` prices the
-    COMPACT bank bytes (``compact_leaf_bank_bytes``) against
-    ``FUSED_VMEM_BANK_BYTES`` -- the whole point of the compression is that
-    the VMEM variant survives to much larger indexes. Returns the same
-    ``(ids, kwv)`` contract, bit-identical to the full-width kernels.
+    COMPACT bank's VMEM footprint against ``FUSED_VMEM_BANK_BYTES`` -- the
+    point of the compression is that the VMEM variant survives to larger
+    indexes. Returns the same ``(ids, kwv)`` contract, bit-identical to the
+    full-width kernels.
     """
-    if interpret is None:
-        interpret = _on_cpu()
-    if variant not in ("auto", "vmem", "prefetch"):
-        raise ValueError(f"unknown fused-verify variant: {variant!r}")
-    if variant == "auto":
-        K, OBJ = obj_x.shape
-        Wl = obj_cbm.shape[2]
-        big = compact_leaf_bank_bytes(K, OBJ, Wl) > FUSED_VMEM_BANK_BYTES
-        variant = "prefetch" if big else "vmem"
-    M = q_rects.shape[0]
-    bm_ = min(bm, max(M, 1))
-    qr = _pad_dim(jnp.asarray(q_rects, jnp.float32), 0, bm_)
-    qc = _pad_dim(jnp.asarray(q_cbm, jnp.uint32), 0, bm_)
-    qs = _pad_dim(jnp.asarray(q_sig, jnp.uint32), 0, bm_)
-    tl = _pad_dim(jnp.asarray(top_leaf, jnp.int32), 0, bm_)
-    ok = _pad_dim(jnp.asarray(leaf_ok, jnp.int8), 0, bm_)
-    bank = (
+    K, OBJ = obj_x.shape
+    kernel = (
+        fused_verify_prefetch_compact
+        if pick_fused_variant(K, OBJ, obj_cbm.shape[2], True, variant) == "prefetch"
+        else fused_verify_compact
+    )
+    return kernel(
+        jnp.asarray(q_rects, jnp.float32), jnp.asarray(q_cbm, jnp.uint32),
+        jnp.asarray(q_sig, jnp.uint32), jnp.asarray(top_leaf, jnp.int32),
+        jnp.asarray(leaf_ok, jnp.int8),
         jnp.asarray(obj_x, jnp.float32), jnp.asarray(obj_y, jnp.float32),
         jnp.asarray(obj_cbm, jnp.uint32), jnp.asarray(obj_sig, jnp.uint32),
         jnp.asarray(obj_id, jnp.int32),
+        bo=bo, interpret=_interpret(),
     )
-    if variant == "prefetch":
-        ids, kwv = fused_verify_prefetch_compact(
-            qr, qc, qs, tl, ok, *bank, interpret=interpret
-        )
-    else:
-        ids, kwv = fused_verify_compact(
-            qr, qc, qs, tl, ok, *bank, bm=bm_, interpret=interpret
-        )
-    return ids[:M], kwv[:M]
 
 
 def cdf_bank_forward(
     params: Dict[str, jax.Array], x: jax.Array, bn: int = 256, bb: int = 64,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """(N, B) CDF values for the whole MLP bank at points x."""
-    if interpret is None:
-        interpret = _on_cpu()
     N = x.shape[0]
     B = params["w0"].shape[0]
     bn_ = min(bn, max(N, 1))
     bb_ = min(bb, max(B, 1))
     xp = _pad_dim(jnp.asarray(x, jnp.float32), 0, bn_)
     pp = {k: _pad_dim(v, 0, bb_) for k, v in params.items()}
-    out = cdf_mlp_bank(pp, xp, bn=bn_, bb=bb_, interpret=interpret)
+    out = cdf_mlp_bank(pp, xp, bn=bn_, bb=bb_, interpret=_interpret())
     return out[:N, :B]
 
 
@@ -489,7 +475,9 @@ __all__ = [
     "leaf_bank_bytes",
     "match_subscriptions",
     "pack_query_words",
+    "pick_fused_variant",
     "remap_query_words",
+    "resident_bank_vmem_bytes",
     "verify_candidates",
     "verify_candidates_compact",
     "cdf_bank_forward",

@@ -3,11 +3,13 @@
 After filtering, each query holds a capacity-padded candidate list (gathered
 from the leaf inverted files). The kernel verifies in-rectangle membership +
 keyword bitmap overlap + validity for a (query-tile x candidate-tile) block
-entirely in VMEM. The bitmap plane ``(BM, BC, W)`` is the big operand; the
-word axis collapses in one packed ``any``-reduction (popcount-style) so only
-``(BM, BC)`` registers accumulate. Candidates re-check in exact f32 here --
-this is the stage that guarantees the narrow-plane descent (frontier.py)
-cannot change reported ids.
+entirely in VMEM. The bitmap plane is the big operand; the wrappers hand
+it to the kernel word-major (``(BM, W, BC)``, candidates on the lanes) so
+the word axis collapses in one sublane any-reduction (``keyword.word_hit``)
+and only ``(BM, BC)`` registers accumulate. Candidates re-check in exact
+f32 here -- this is the stage that guarantees the narrow-plane descent
+(frontier.py) cannot change reported ids. Validity and matches cross the
+kernel boundary as int32 (v5e has no int8 vector compare).
 """
 from __future__ import annotations
 
@@ -17,22 +19,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .keyword import word_hit
 
-def _verify_kernel(q_rects_ref, q_bm_ref, cx_ref, cy_ref, cbm_ref, cv_ref, out_ref):
-    qr = q_rects_ref[...]  # (BM, 4)
-    cx = cx_ref[...]  # (BM, BC)
-    cy = cy_ref[...]
-    inr = (
+
+def _in_rect(qr, cx, cy):
+    return (
         (cx >= qr[:, 0:1])
         & (cx <= qr[:, 2:3])
         & (cy >= qr[:, 1:2])
         & (cy <= qr[:, 3:4])
     )
-    qb = q_bm_ref[...]  # (BM, W)
-    cb = cbm_ref[...]  # (BM, BC, W)
-    # packed word-plane AND + single any-reduction per tile (popcount-style)
-    kw = jnp.any((cb & qb[:, None, :]) != 0, axis=-1)  # (BM, BC)
-    out_ref[...] = (inr & kw & (cv_ref[...] > 0)).astype(jnp.int8)
+
+
+def _verify_kernel(q_rects_ref, q_bm_ref, cx_ref, cy_ref, cbm_ref, cv_ref, out_ref):
+    inr = _in_rect(q_rects_ref[...], cx_ref[...], cy_ref[...])  # (BM, BC)
+    kw = word_hit(cbm_ref[...], q_bm_ref[...])  # (BM, W, BC) x (BM, W)
+    out_ref[...] = (inr & kw & (cv_ref[...] > 0)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bc", "interpret"))
@@ -52,7 +54,7 @@ def skr_verify(
     bm = min(bm, M)
     bc = min(bc, C)
     grid = (pl.cdiv(M, bm), pl.cdiv(C, bc))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _verify_kernel,
         grid=grid,
         in_specs=[
@@ -60,37 +62,29 @@ def skr_verify(
             pl.BlockSpec((bm, W), lambda i, j: (i, 0)),
             pl.BlockSpec((bm, bc), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, bc, W), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((bm, W, bc), lambda i, j: (i, 0, j)),
             pl.BlockSpec((bm, bc), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((bm, bc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, C), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((M, C), jnp.int32),
         interpret=interpret,
-    )(q_rects, q_bm, cand_x, cand_y, cand_bm, cand_valid)
+    )(q_rects, q_bm, cand_x, cand_y, jnp.swapaxes(cand_bm, 1, 2),
+      cand_valid.astype(jnp.int32))
+    return out.astype(jnp.int8)
 
 
 def _verify_compact_kernel(
     q_rects_ref, q_cbm_ref, q_sig_ref, cx_ref, cy_ref,
     cbm_ref, csig_ref, cv_ref, out_ref,
 ):
-    qr = q_rects_ref[...]  # (BM, 4)
-    cx = cx_ref[...]  # (BM, OBJ)
-    cy = cy_ref[...]
-    inr = (
-        (cx >= qr[:, 0:1])
-        & (cx <= qr[:, 2:3])
-        & (cy >= qr[:, 1:2])
-        & (cy <= qr[:, 3:4])
-    )
-    qc = q_cbm_ref[...]  # (BM, 1, Wl) -- this slot's remapped query words
-    qs = q_sig_ref[...]  # (BM, 1)
+    inr = _in_rect(q_rects_ref[...], cx_ref[...], cy_ref[...])  # (BM, BO)
     # one-word signature prefilter (implied by the word test -- kw unchanged)
-    sig_hit = (csig_ref[...] & qs) != 0  # (BM, OBJ)
-    kw = sig_hit & jnp.any((cbm_ref[...] & qc) != 0, axis=-1)  # (BM, OBJ)
-    out_ref[...] = (inr & kw & (cv_ref[...] > 0)).astype(jnp.int8)
+    sig_hit = (csig_ref[...] & q_sig_ref[...]) != 0  # (BM, BO) x (BM, 1)
+    kw = sig_hit & word_hit(cbm_ref[...], q_cbm_ref[...])  # (BM, Wl, BO)
+    out_ref[...] = (inr & kw & (cv_ref[...] > 0)).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bm", "bo", "interpret"))
 def skr_verify_compact(
     q_rects: jax.Array,  # (M, 4)
     q_cbm: jax.Array,  # (M, T, Wl) leaf-local remapped query words
@@ -101,34 +95,52 @@ def skr_verify_compact(
     cand_sig: jax.Array,  # (M, T*OBJ) candidate signatures
     cand_valid: jax.Array,  # (M, T*OBJ) int8
     bm: int = 8,
+    bo: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """Compact-vocabulary twin of ``skr_verify`` (DESIGN.md §3.5).
 
     Candidates arrive leaf-slot-major (T slots of OBJ objects each, the
     fused kernels' ordering) because the query-side words differ PER SLOT:
-    each selected leaf has its own vocabulary, so the candidate grid tiles
-    over slots -- block ``(BM, OBJ)`` at slot ``j`` pairs with query words
-    ``q_cbm[:, j]`` -- instead of skr_verify's flat candidate axis."""
+    each selected leaf has its own vocabulary. The kernel therefore runs
+    slot-major -- every operand is viewed ``(T, M, OBJ)`` (bitmaps
+    ``(T, M, Wl, OBJ)``, word-major) so a block ``(BM, BO)`` of slot ``t``
+    pairs with query words ``q_cbm[:, t]`` -- and the ``(T, M, OBJ)`` result
+    is put back in leaf-slot-major order. ``OBJ`` is tiled by ``bo``
+    (padded when ``bo`` does not divide it)."""
     M, T = q_sig.shape
     Wl = q_cbm.shape[2]
     OBJ = cand_x.shape[1] // T
     bm = min(bm, M)
-    grid = (pl.cdiv(M, bm), T)
-    return pl.pallas_call(
+    bo = min(bo, OBJ)
+    OBJp = pl.cdiv(OBJ, bo) * bo
+
+    def slot_major(a):  # (M, T*OBJ, ...) -> (T, M, ..., OBJp), zero pads
+        a = jnp.moveaxis(a.reshape(M, T, OBJ, *a.shape[2:]), 2, -1)
+        a = jnp.moveaxis(a, 1, 0)
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, OBJp - OBJ)])
+
+    grid = (T, pl.cdiv(M, bm), OBJp // bo)
+    row = pl.BlockSpec((None, bm, bo), lambda t, i, o: (t, i, o))
+    out = pl.pallas_call(
         _verify_compact_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, 4), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, 1, Wl), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, OBJ), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, OBJ), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, OBJ, Wl), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((bm, OBJ), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, OBJ), lambda i, j: (i, j)),
+            pl.BlockSpec((bm, 4), lambda t, i, o: (i, 0)),
+            pl.BlockSpec((None, bm, Wl), lambda t, i, o: (t, i, 0)),
+            pl.BlockSpec((None, bm, 1), lambda t, i, o: (t, i, 0)),
+            row,
+            row,
+            pl.BlockSpec((None, bm, Wl, bo), lambda t, i, o: (t, i, 0, o)),
+            row,
+            row,
         ],
-        out_specs=pl.BlockSpec((bm, OBJ), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, T * OBJ), jnp.int8),
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((T, M, OBJp), jnp.int32),
         interpret=interpret,
-    )(q_rects, q_cbm, q_sig, cand_x, cand_y, cand_cbm, cand_sig, cand_valid)
+    )(
+        q_rects, jnp.moveaxis(q_cbm, 1, 0), q_sig.T[:, :, None],
+        slot_major(cand_x), slot_major(cand_y), slot_major(cand_cbm),
+        slot_major(cand_sig), slot_major(cand_valid.astype(jnp.int32)),
+    )
+    return jnp.moveaxis(out[:, :, :OBJ], 0, 1).reshape(M, T * OBJ).astype(jnp.int8)

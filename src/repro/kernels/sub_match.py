@@ -14,22 +14,24 @@ nothing, the same contract as an empty SKR query).
 
 The kernel reuses the two bandwidth tricks of the descent kernels:
 
-* **packed object word planes** (PR 7 / ops.pack_query_words): each
-  arriving object carries only its nonzero bitmap words -- ``(BN, Wp)``
-  ids + values with Wp a static power-of-two bucket -- and the
-  subscription-side words are gathered *inside* the kernel from the
-  word-major ``(W, BS)`` VMEM tile, so the big operand is ``(BN, Wp, BS)``
-  instead of ``(BN, W, BS)``;
-* **one-word OR-fold signatures** (PR 9): a per-side 32-bit OR of all
+* **packed object word planes** (ops.pack_query_words): each arriving
+  object carries only its nonzero bitmap words -- ``(BN, Wp)`` ids +
+  values with Wp a static power-of-two bucket -- and the XLA gather that
+  feeds the kernel pulls just those words out of the word-major ``(W, S)``
+  subscription block, so the big operand is ``(N, Wp, S)`` instead of
+  ``(N, W, S)`` (the TPU compiler has no in-kernel vector gather);
+* **one-word OR-fold signatures**: a per-side 32-bit OR of all
   words; ``(o_sig & s_sig) != 0`` is a necessary condition for any shared
   bit, ANDed in as a register-cheap prefilter (empty slots on either side
   carry signature 0 and are therefore inert -- padding needs no separate
   validity plane).
 
-Grid: ``(cdiv(N, bn), cdiv(S, bs))`` object x subscription tiles; output is
-the (N, S) int8 match matrix. The ref twin is ``ref.sub_match_ref``; the
-brute-force ground truth (set semantics, no bitmaps at all) is
-``core.query.match_subscriptions_bruteforce``.
+Grid: ``(cdiv(N, bn), cdiv(S, bs))`` object x subscription tiles, the
+subscriptions on the lanes (rects as ``(4, S)`` planes, word planes
+word-major so the keyword any-reduction is a sublane max --
+``keyword.word_hit``); output is the (N, S) int8 match matrix. The ref
+twin is ``ref.sub_match_ref``; the brute-force ground truth (set
+semantics, no bitmaps at all) is ``core.query.match_subscriptions_bruteforce``.
 """
 from __future__ import annotations
 
@@ -39,28 +41,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .keyword import word_hit
+
 
 def _sub_match_kernel(
-    o_pts_ref, o_wids_ref, o_bits_ref, o_sig_ref, s_rects_ref, s_bm_ref, s_sig_ref, out_ref
+    o_pts_ref, o_bits_ref, o_sig_ref, s_rects_ref, s_words_ref, s_sig_ref, out_ref
 ):
     op = o_pts_ref[...]  # (BN, 2) f32 object points
-    sr = s_rects_ref[...]  # (BS, 4) f32 subscription rects (NEVER_RECT pads)
+    sr = s_rects_ref[...]  # (4, BS) f32 subscription rect planes (NEVER_RECT pads)
     x = op[:, 0:1]  # (BN, 1)
     y = op[:, 1:2]
     inr = (
-        (x >= sr[:, 0][None, :])
-        & (x <= sr[:, 2][None, :])
-        & (y >= sr[:, 1][None, :])
-        & (y <= sr[:, 3][None, :])
+        (x >= sr[0:1]) & (x <= sr[2:3]) & (y >= sr[1:2]) & (y <= sr[3:4])
     )  # (BN, BS) point-in-rect
-    osig = o_sig_ref[...]  # (BN, 1) u32 OR-fold object signatures
-    ssig = s_sig_ref[...]  # (BS, 1) u32 OR-fold subscription signatures
-    sig = (osig & ssig[:, 0][None, :]) != 0  # (BN, BS) shared-bit prefilter
-    wid = o_wids_ref[...].astype(jnp.int32)  # (BN, Wp) packed object word ids
-    sw = s_bm_ref[...].swapaxes(0, 1)  # (W, BS) word-major subscription tile
-    g = sw[wid]  # (BN, Wp, BS) VMEM gather of the objects' words
-    kw = jnp.any((g & o_bits_ref[...][:, :, None]) != 0, axis=1)  # (BN, BS)
-    out_ref[...] = (inr & sig & kw).astype(jnp.int8)
+    # (BN, 1) x (1, BS) OR-fold signatures: shared-bit prefilter
+    sig = (o_sig_ref[...] & s_sig_ref[...]) != 0
+    kw = word_hit(s_words_ref[...], o_bits_ref[...])  # (BN, Wp, BS) x (BN, Wp)
+    out_ref[...] = (inr & sig & kw).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bs", "interpret"))
@@ -80,23 +77,23 @@ def sub_match(
     N = o_pts.shape[0]
     S = s_rects.shape[0]
     Wp = o_wids.shape[1]
-    W = s_bm.shape[1]
     bn = min(bn, N)
     bs = min(bs, S)
+    s_words = s_bm.T[o_wids.astype(jnp.int32)]  # (N, Wp, S) objects' words
     grid = (pl.cdiv(N, bn), pl.cdiv(S, bs))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _sub_match_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, 2), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, Wp), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, Wp), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bs, 4), lambda i, j: (j, 0)),
-            pl.BlockSpec((bs, W), lambda i, j: (j, 0)),
-            pl.BlockSpec((bs, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((4, bs), lambda i, j: (0, j)),
+            pl.BlockSpec((bn, Wp, bs), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, bs), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bn, bs), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((N, S), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((N, S), jnp.int32),
         interpret=interpret,
-    )(o_pts, o_wids, o_bits, o_sig, s_rects, s_bm, s_sig)
+    )(o_pts, o_bits, o_sig, s_rects.T, s_words, s_sig.T)
+    return out.astype(jnp.int8)

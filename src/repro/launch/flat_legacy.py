@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.wisk import WiskServeConfig
 from ..kernels.ref import skr_filter_ref, skr_verify_ref
-from ..sharding.compat import shard_map
+from jax import shard_map
 from ..sharding.rules import default_rules, dp_axes, spec_for
 
 OBJ_PER_LEAF = 512

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..sharding.compat import shard_map
+from jax import shard_map
 
 from ..kernels import ops
 from ..serve.delta import DeltaBuffer, DeltaLog, partition_delta

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..sharding.compat import shard_map
+from jax import shard_map
 
 from ..configs.base import ArchConfig
 from ..sharding.rules import constrain, dp_axes

@@ -62,15 +62,8 @@ class Computation:
 
 
 def cost_analysis_dict(compiled) -> Dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of per-partition dicts; newer jax
-    returns the dict directly. Callers always want the flat dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 def split_computations(hlo: str) -> Tuple[Dict[str, Computation], Optional[str]]:

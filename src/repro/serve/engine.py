@@ -306,9 +306,9 @@ def _verify_leaves(
     ``compact=False`` forces the full-width slab.
 
     ``fused_variant`` picks the fused kernel: None (auto) compares the leaf
-    bank's bytes (compact bytes when the compact bank is in play) against
+    bank's VMEM footprint (the compact bank's when it is in play) against
     ``ops.FUSED_VMEM_BANK_BYTES`` -- the VMEM-resident kernel below the
-    cutoff, the scalar-prefetched (M, T)-grid kernel above it -- so banks
+    cutoff, the kernel that DMAs only the selected leaf tiles above it -- so banks
     beyond VMEM keep the fused path instead of falling back to the unfused
     HBM round-trip. ``"vmem"``/``"prefetch"`` force a kernel (A/B rows,
     beyond-VMEM tests).

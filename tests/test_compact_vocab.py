@@ -138,16 +138,19 @@ def test_fused_verify_compact_variants_equal():
 
 
 def test_compact_auto_prices_compact_bank(monkeypatch):
-    """variant="auto" prices the COMPACT bank bytes, not the full-width
-    bank: with the cutoff between the two, the VMEM compact kernel must be
-    selected even though the full-width bank would have forced prefetch."""
+    """variant="auto" prices the COMPACT bank's VMEM footprint, not the
+    full-width bank's: with the cutoff between the two, the VMEM compact
+    kernel must be selected even though the full-width bank would have
+    forced prefetch. (A slab narrower than 128 words still fills 128 lanes
+    in VMEM, so the full width here is a lane-dense 256 words.)"""
     rng = np.random.default_rng(47)
-    k, obj, w = 16, 16, 8
+    k, obj, w = 16, 16, 256
     _, compact = _compact_case(rng, 6, 3, k, obj, w)
     Wl = int(np.asarray(compact[7]).shape[2])
-    cut = (ops.compact_leaf_bank_bytes(k, obj, Wl)
-           + ops.leaf_bank_bytes(k, obj, w)) // 2
-    assert ops.compact_leaf_bank_bytes(k, obj, Wl) < cut < ops.leaf_bank_bytes(k, obj, w)
+    small = ops.resident_bank_vmem_bytes(k, obj, Wl, 4)
+    wide = ops.resident_bank_vmem_bytes(k, obj, w, 3)
+    cut = (small + wide) // 2
+    assert small < cut < wide
     monkeypatch.setattr(ops, "FUSED_VMEM_BANK_BYTES", cut)
     calls = []
     real = ops.fused_verify_compact

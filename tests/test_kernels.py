@@ -220,10 +220,12 @@ def test_fused_verify_sweep(m, t, k, obj, w):
 
 
 def test_fused_verify_block_size_invariance():
+    """Object tiles that divide OBJ (8) and that force padding (16) give
+    the same ids and counts, on both fused variants."""
     rng = np.random.default_rng(11)
     args = _fused_operands(rng, 21, 5, 12, 24, 5)
-    a_ids, a_kwv = ops.fused_gather_verify(*args, bm=4)
-    b_ids, b_kwv = ops.fused_gather_verify(*args, bm=16)
+    a_ids, a_kwv = ops.fused_gather_verify(*args, bo=8, variant="vmem")
+    b_ids, b_kwv = ops.fused_gather_verify(*args, bo=16, variant="prefetch")
     np.testing.assert_array_equal(np.asarray(a_ids), np.asarray(b_ids))
     np.testing.assert_array_equal(np.asarray(a_kwv), np.asarray(b_kwv))
 

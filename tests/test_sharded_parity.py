@@ -37,7 +37,7 @@ from repro.launch.wisk_serve import (
 )
 from repro.serve.engine import IndexSnapshot, retrieve_knn, retrieve_workload
 from repro.serve.plan import PlanCache
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 from test_query_parity import _build_index, _grid_clusters, flat_index
 
