@@ -34,6 +34,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import faulthandler  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -46,8 +47,8 @@ CHIP_DIR = Path(__file__).resolve().parent
 ROOT = CHIP_DIR.parents[1]
 sys.path.insert(0, str(CHIP_DIR))
 
-RUNS_DIR = CHIP_DIR / ".runs"  # traces and stall stacks (git-ignored)
-STACKS_SHOWN = 12000  # characters of a stall's stack dump printed to the log
+RUNS_DIR = CHIP_DIR / ".runs"  # traces (git-ignored)
+STACKS_SHOWN = 12000  # characters of the stalls' sampled stacks printed to the log
 CACHE_DIR = CHIP_DIR / ".cache" / "jax"  # persistent compile cache (git-ignored)
 _COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowered",
@@ -216,7 +217,7 @@ def main(argv=None, require_tpu: bool = True, root: Path = ROOT, store_dir: Path
     before = dict(clock.count)
     host0 = _host_counters()
     trace_dir = RUNS_DIR / f"{spec.name}-{args.seed}-trace"
-    watch = Watch(RUNS_DIR / f"{spec.name}-{args.seed}-stalls.txt")
+    watch = Watch()
     reduction = None
     if args.trace:
         import trace_reduce
@@ -263,10 +264,9 @@ def main(argv=None, require_tpu: bool = True, root: Path = ROOT, store_dir: Path
     say("window_gc", **watch.gc_summary())
     for stall in watch.stalls(rec.calls):
         say("stall", **stall)
-    dumps = watch.dumps()
-    if dumps:
-        print(f"stall_stacks: the watchdog's dump, every thread, first {STACKS_SHOWN} characters\n"
-              + dumps[:STACKS_SHOWN], flush=True)
+    stacks = watch.stack_report(STACKS_SHOWN)
+    if stacks:
+        print(stacks, flush=True)
     say("served_state_after", **_describe(server))
 
     import check
@@ -330,4 +330,5 @@ def _dispatch_lag_p95(plan, rec) -> float:
 
 
 if __name__ == "__main__":
+    faulthandler.enable(file=sys.stderr, all_threads=True)  # a fatal signal leaves the stacks
     sys.exit(main())
