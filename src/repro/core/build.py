@@ -19,11 +19,11 @@ counters land in ``BuildArtifacts.timings`` / ``.counters``.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Optional
 
 import numpy as np
 
+from .. import obs
 from .cdf import CDFBank, build_cdf_bank
 from .index import assemble_index
 from .itemsets import expand_queries, mine_frequent_itemsets
@@ -100,63 +100,58 @@ def build_wisk(
         idx = stratified_sample(workload, cfg.sample_ratio, seed=cfg.seed)
         train_wl = workload.subset(idx)
 
-    t0 = time.perf_counter()
-    itemsets, members = ([], [])
-    if cfg.use_itemsets:
-        itemsets, members = mine_frequent_itemsets(
-            dataset, min_support=cfg.itemset_min_support, max_size=cfg.itemset_max_size
+    with obs.span("build.itemset_mining", into=timings):
+        itemsets, members = ([], [])
+        if cfg.use_itemsets:
+            itemsets, members = mine_frequent_itemsets(
+                dataset, min_support=cfg.itemset_min_support, max_size=cfg.itemset_max_size
+            )
+
+    with obs.span("build.cdf_training", into=timings):
+        bank = build_cdf_bank(
+            dataset,
+            itemsets=itemsets,
+            itemset_members=members,
+            high_thresh=cfg.cdf_high_thresh,
+            low_thresh=cfg.cdf_low_thresh,
+            n_steps=cfg.cdf_train_steps,
+            seed=cfg.seed,
+            force_class=cfg.cdf_force_class,
         )
-    timings["itemset_mining"] = time.perf_counter() - t0
 
-    t0 = time.perf_counter()
-    bank = build_cdf_bank(
-        dataset,
-        itemsets=itemsets,
-        itemset_members=members,
-        high_thresh=cfg.cdf_high_thresh,
-        low_thresh=cfg.cdf_low_thresh,
-        n_steps=cfg.cdf_train_steps,
-        seed=cfg.seed,
-        force_class=cfg.cdf_force_class,
-    )
-    timings["cdf_training"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    q_entries, q_signs = expand_queries(
-        train_wl, itemsets, dataset.vocab_size, use_itemsets=cfg.use_itemsets
-    )
-    part = generate_bottom_clusters(
-        dataset, train_wl, bank, q_entries, q_signs, cfg.partition, mode=cfg.construction
-    )
-    timings["partitioning"] = time.perf_counter() - t0
+    with obs.span("build.partitioning", into=timings):
+        q_entries, q_signs = expand_queries(
+            train_wl, itemsets, dataset.vocab_size, use_itemsets=cfg.use_itemsets
+        )
+        part = generate_bottom_clusters(
+            dataset, train_wl, bank, q_entries, q_signs, cfg.partition, mode=cfg.construction
+        )
 
     hierarchy = None
     if cfg.build_hierarchy and part.clusters.k > cfg.packing.min_nodes:
-        t0 = time.perf_counter()
-        # label clusters with (sampled) queries for the packing state
-        mq = min(cfg.packing.max_label_queries, train_wl.m)
-        sel = rng.choice(train_wl.m, size=mq, replace=False) if train_wl.m > mq else np.arange(train_wl.m)
-        lbl_wl = train_wl.subset(np.sort(sel))
-        labels = cluster_query_labels(part.clusters, lbl_wl)
-        pk = cfg.packing
-        if cfg.accelerated:
-            pk = dataclasses.replace(pk, spectral_ratio=cfg.cluster_ratio)
-        hierarchy = build_hierarchy(labels, part.clusters.mbrs, pk, mode=cfg.construction)
-        timings["packing"] = time.perf_counter() - t0
+        with obs.span("build.packing", into=timings):
+            # label clusters with (sampled) queries for the packing state
+            mq = min(cfg.packing.max_label_queries, train_wl.m)
+            sel = rng.choice(train_wl.m, size=mq, replace=False) if train_wl.m > mq else np.arange(train_wl.m)
+            lbl_wl = train_wl.subset(np.sort(sel))
+            labels = cluster_query_labels(part.clusters, lbl_wl)
+            pk = cfg.packing
+            if cfg.accelerated:
+                pk = dataclasses.replace(pk, spectral_ratio=cfg.cluster_ratio)
+            hierarchy = build_hierarchy(labels, part.clusters.mbrs, pk, mode=cfg.construction)
 
-    t0 = time.perf_counter()
-    index = assemble_index(
-        dataset,
-        part.clusters,
-        hierarchy,
-        meta=dict(
-            n_clusters=part.clusters.k,
-            n_itemsets=len(itemsets),
-            accelerated=cfg.accelerated,
-            cdf_loss=bank.train_loss,
-        ),
-    )
-    timings["assembly"] = time.perf_counter() - t0
+    with obs.span("build.assembly", into=timings):
+        index = assemble_index(
+            dataset,
+            part.clusters,
+            hierarchy,
+            meta=dict(
+                n_clusters=part.clusters.k,
+                n_itemsets=len(itemsets),
+                accelerated=cfg.accelerated,
+                cdf_loss=bank.train_loss,
+            ),
+        )
     timings["total"] = sum(timings.values())
     counters = dict(
         partition_rounds=part.n_rounds,
@@ -230,47 +225,44 @@ def warm_start_rebuild(
     cfg = config or BuildConfig()
     timings: Dict[str, float] = {}
 
-    t0 = time.perf_counter()
-    if assign is None:
-        assign = prev.partition.clusters.assign
-    if assign.shape[0] != dataset.n:
-        raise ValueError(
-            f"assignment covers {assign.shape[0]} objects, dataset has {dataset.n}; "
-            "pass DeltaLog.merged_assignment() when rebuilding over a grown dataset"
+    with obs.span("build.drift_localization", into=timings):
+        if assign is None:
+            assign = prev.partition.clusters.assign
+        if assign.shape[0] != dataset.n:
+            raise ValueError(
+                f"assignment covers {assign.shape[0]} objects, dataset has {dataset.n}; "
+                "pass DeltaLog.merged_assignment() when rebuilding over a grown dataset"
+            )
+        clusters0 = ClusterSet.from_assignment(dataset, np.asarray(assign, np.int32))
+        if regressed is None:
+            if prev.train_workload is None:
+                raise ValueError("prev.train_workload missing; pass regressed explicitly")
+            trained_prof = leaf_cost_profile(dataset, clusters0, prev.train_workload)
+            observed_prof = leaf_cost_profile(dataset, clusters0, workload)
+            regressed = regressed_leaves(trained_prof, observed_prof, ratio=regress_ratio)
+
+    with obs.span("build.partitioning", into=timings):
+        q_entries, q_signs = expand_queries(
+            workload, prev.itemsets, dataset.vocab_size, use_itemsets=cfg.use_itemsets
         )
-    clusters0 = ClusterSet.from_assignment(dataset, np.asarray(assign, np.int32))
-    if regressed is None:
-        if prev.train_workload is None:
-            raise ValueError("prev.train_workload missing; pass regressed explicitly")
-        trained_prof = leaf_cost_profile(dataset, clusters0, prev.train_workload)
-        observed_prof = leaf_cost_profile(dataset, clusters0, workload)
-        regressed = regressed_leaves(trained_prof, observed_prof, ratio=regress_ratio)
-    timings["drift_localization"] = time.perf_counter() - t0
+        refined = refine_partition(
+            dataset, workload, prev.bank, q_entries, q_signs,
+            clusters0, regressed, cfg.partition, mode=cfg.construction,
+        )
 
-    t0 = time.perf_counter()
-    q_entries, q_signs = expand_queries(
-        workload, prev.itemsets, dataset.vocab_size, use_itemsets=cfg.use_itemsets
-    )
-    refined = refine_partition(
-        dataset, workload, prev.bank, q_entries, q_signs,
-        clusters0, regressed, cfg.partition, mode=cfg.construction,
-    )
-    timings["partitioning"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    hierarchy = graft_hierarchy(prev.hierarchy, refined.source)
-    index = assemble_index(
-        dataset,
-        refined.clusters,
-        hierarchy,
-        meta=dict(
-            n_clusters=refined.clusters.k,
-            warm_start=True,
-            refined_leaves=refined.n_refined,
-            kept_clusters=refined.n_kept,
-        ),
-    )
-    timings["assembly"] = time.perf_counter() - t0
+    with obs.span("build.assembly", into=timings):
+        hierarchy = graft_hierarchy(prev.hierarchy, refined.source)
+        index = assemble_index(
+            dataset,
+            refined.clusters,
+            hierarchy,
+            meta=dict(
+                n_clusters=refined.clusters.k,
+                warm_start=True,
+                refined_leaves=refined.n_refined,
+                kept_clusters=refined.n_kept,
+            ),
+        )
     timings["total"] = sum(timings.values())
     counters = dict(
         refined_leaves=refined.n_refined,

@@ -69,6 +69,7 @@ def cdf_mlp_bank(
         out_specs=pl.BlockSpec((bn, bb), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, B), jnp.float32),
         interpret=interpret,
+        name="cdf_mlp",
     )(
         x[:, None],
         params["w0"],

@@ -77,6 +77,7 @@ def _frontier_call(q_rects, q_bm, planes, words, f_valid, bm, bf, interpret):
         out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, F), jnp.int32),
         interpret=interpret,
+        name="frontier_filter",
     )(q_rects, q_bm, planes, words, f_valid.astype(jnp.int32))
     return out.astype(jnp.int8)
 

@@ -246,6 +246,7 @@ def _fused_call(
         ],
         compiler_params=params,
         interpret=interpret,
+        name="fused_verify" + ("" if resident else "_prefetch") + ("_compact" if compact else ""),
     )(*prefetch, q_rects.reshape(M, 1, 4), qw, ox, oy, ow, *rest)
     ids = ids.reshape(M, T, OBJp)[:, :, :OBJ].reshape(M, T * OBJ)
     return ids, kwv[:, 0, 0].reshape(M, T)

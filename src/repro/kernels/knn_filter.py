@@ -72,6 +72,7 @@ def _knn_call(q_pts, q_bm, planes, words, f_valid, bm, bf, interpret):
         out_specs=pl.BlockSpec((bm, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
         interpret=interpret,
+        name="knn_filter",
     )(q_pts, q_bm, planes, words, f_valid.astype(jnp.int32))
 
 

@@ -68,4 +68,5 @@ def skr_filter(
         out_specs=pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, K), jnp.int8),
         interpret=interpret,
+        name="skr_filter",
     )(q_rects, q_bm, n_mbrs, n_bm)

@@ -68,6 +68,7 @@ def skr_verify(
         out_specs=pl.BlockSpec((bm, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, C), jnp.int32),
         interpret=interpret,
+        name="skr_verify",
     )(q_rects, q_bm, cand_x, cand_y, jnp.swapaxes(cand_bm, 1, 2),
       cand_valid.astype(jnp.int32))
     return out.astype(jnp.int8)
@@ -138,6 +139,7 @@ def skr_verify_compact(
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((T, M, OBJp), jnp.int32),
         interpret=interpret,
+        name="skr_verify_compact",
     )(
         q_rects, jnp.moveaxis(q_cbm, 1, 0), q_sig.T[:, :, None],
         slot_major(cand_x), slot_major(cand_y), slot_major(cand_cbm),

@@ -95,5 +95,6 @@ def sub_match(
         out_specs=pl.BlockSpec((bn, bs), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, S), jnp.int32),
         interpret=interpret,
+        name="sub_match",
     )(o_pts, o_bits, o_sig, s_rects.T, s_words, s_sig.T)
     return out.astype(jnp.int8)

@@ -64,6 +64,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 
+from .. import obs
 from ..kernels import ops
 from ..serve.delta import DeltaBuffer, DeltaLog, partition_delta
 from ..serve.engine import (
@@ -132,9 +133,12 @@ def serve_batch(
     ``-1`` fill, ``counts``, Eq.1 counters); only the pad/slice runs on
     host.
     """
-    rects, bms, m = pad_queries_to_bucket(q_rects, q_bm, minimum_bucket)
+    with obs.span("wisk.prep"):
+        rects, bms, m = pad_queries_to_bucket(q_rects, q_bm, minimum_bucket)
+    obs.count("skr.rows", m)
+    obs.count("skr.pad_rows", rects.shape[0] - m)
     out = retrieve(
-        snap, jnp.asarray(rects), jnp.asarray(bms), max_leaves, mode=mode,
+        snap, rects, bms, max_leaves, mode=mode,
         plan_cache=plan_cache, delta=delta, fused=fused, compact=compact,
     )
     per_query = ("ids", "counts", "nodes_checked", "nodes_scanned", "verified", "overflow")
@@ -174,9 +178,10 @@ def serve_knn_batch(
     (dist^2, id) with ``-1`` fill, plus Eq.1 counters, pads sliced off.
     Host-side wrapper around the jit-traced descent.
     """
-    pts, bms, m = pad_knn_queries_to_bucket(points, q_bm, minimum_bucket)
+    with obs.span("wisk.prep"):
+        pts, bms, m = pad_knn_queries_to_bucket(points, q_bm, minimum_bucket)
     out = retrieve_knn(
-        snap, jnp.asarray(pts), jnp.asarray(bms), k, plan_cache=plan_cache,
+        snap, pts, bms, k, plan_cache=plan_cache,
         delta=delta, knn_dtype=knn_dtype, compact=compact,
     )
     per_query = ("ids", "dist2", "nodes_checked", "verified", "leaves_verified", "pruned")
@@ -560,7 +565,7 @@ def _knn_shard_body(
         snap, points, q_bm, k, kb, plan, delta, (wids, bits) if narrow else None,
         cbank=_snap_cbank(snap, compact),
     )
-    top_d, top_id, nodes_checked, verified, leaves_verified, pruned, _, _ = result
+    top_d, top_id, nodes_checked, verified, leaves_verified, pruned, _, _, _ = result
     fin = jnp.isfinite(top_d[:, :k])
     ids = jnp.where(fin, top_id[:, :k], -1)
     return (
@@ -854,15 +859,16 @@ def serve_index_sharded(
         )
 
     widths, out = _converge_widths_indexed(cache, "skr_ix", S, n_links, run)
-    ids, counts, nodes_checked, kw_scanned, overflow, _ = out
+    with obs.span("wisk.fetch"):
+        ids, counts, nodes_checked, kw_scanned, overflow = (np.asarray(a) for a in out[:5])
     used = [psnap.local_root_width(), *widths]
     return dict(
-        ids=np.asarray(ids)[:m],
-        counts=np.asarray(counts)[:m],
-        nodes_checked=np.asarray(nodes_checked, np.int64)[:m],
+        ids=ids[:m],
+        counts=counts[:m],
+        nodes_checked=nodes_checked[:m].astype(np.int64),
         nodes_scanned=np.full((m,), sum(used) * S, np.int64),
-        verified=np.asarray(kw_scanned)[:m],
-        overflow=np.asarray(overflow)[:m],
+        verified=kw_scanned[:m],
+        overflow=overflow[:m],
         frontier_widths=np.asarray(used, np.int32),
     )
 
@@ -982,15 +988,17 @@ def serve_knn_index_sharded(
             narrow, compact,
         ),
     )
-    ids, dist2, nodes_checked, verified, leaves_verified, pruned, _ = out
+    with obs.span("wisk.fetch"):
+        ids, dist2, *counters = (np.asarray(a)[:m] for a in out[:6])
+    nodes_checked, verified, leaves_verified, pruned = (c.astype(np.int64) for c in counters)
     used = [psnap.local_root_width(), *widths]
     return dict(
-        ids=np.asarray(ids)[:m],
-        dist2=np.asarray(dist2)[:m],
-        nodes_checked=np.asarray(nodes_checked, np.int64)[:m],
-        verified=np.asarray(verified, np.int64)[:m],
-        leaves_verified=np.asarray(leaves_verified, np.int64)[:m],
-        pruned=np.asarray(pruned, np.int64)[:m],
+        ids=ids,
+        dist2=dist2,
+        nodes_checked=nodes_checked,
+        verified=verified,
+        leaves_verified=leaves_verified,
+        pruned=pruned,
         frontier_widths=np.asarray(used, np.int32),
     )
 
@@ -1133,33 +1141,30 @@ class LiveIndex:
         bypassed (counters are identical either way, so the monitor feed is
         unchanged)."""
         gen = self._gen
-        if gen.partitioned is not None:
-            out = serve_index_sharded(
-                gen.partitioned, q_rects, q_bm, max_leaves,
-                mesh=self.index_mesh, plan_cache=gen.plan_cache,
-                delta=gen.delta(),
-            )
-            self._record(q_rects, q_bm)
-            self.monitor.observe_counters(
-                np.asarray(out["nodes_checked"]), np.asarray(out["verified"])
-            )
-            return out
-        if self.result_cache is not None:
-            out = serve_batch_cached(
-                gen.snapshot, q_rects, q_bm, self.result_cache, max_leaves,
-                plan_cache=gen.plan_cache, delta=gen.delta(),
-            )
-            fresh = ~out["cached"]
-        else:
-            out = serve_batch(
-                gen.snapshot, q_rects, q_bm, max_leaves,
-                plan_cache=gen.plan_cache, delta=gen.delta(),
-            )
+        with obs.span("wisk.serve"):
             fresh = slice(None)
-        self._record(q_rects, q_bm)
-        nc = np.asarray(out["nodes_checked"])[fresh]
-        if nc.size:  # an all-hit batch observed no real descents
-            self.monitor.observe_counters(nc, np.asarray(out["verified"])[fresh])
+            if gen.partitioned is not None:
+                out = serve_index_sharded(
+                    gen.partitioned, q_rects, q_bm, max_leaves,
+                    mesh=self.index_mesh, plan_cache=gen.plan_cache,
+                    delta=gen.delta(),
+                )
+            elif self.result_cache is not None:
+                out = serve_batch_cached(
+                    gen.snapshot, q_rects, q_bm, self.result_cache, max_leaves,
+                    plan_cache=gen.plan_cache, delta=gen.delta(),
+                )
+                fresh = ~out["cached"]
+            else:
+                out = serve_batch(
+                    gen.snapshot, q_rects, q_bm, max_leaves,
+                    plan_cache=gen.plan_cache, delta=gen.delta(),
+                )
+            with obs.span("wisk.observe"):
+                self._record(q_rects, q_bm)
+                nc = np.asarray(out["nodes_checked"])[fresh]
+                if nc.size:  # an all-hit batch observed no real descents
+                    self.monitor.observe_counters(nc, np.asarray(out["verified"])[fresh])
         return out
 
     def serve_knn(self, points, q_bm, k: int) -> Dict[str, np.ndarray]:
@@ -1169,20 +1174,22 @@ class LiveIndex:
         rects, so kNN-driven drift both trips the monitor AND steers the
         rebuild's training workload toward the traffic that tripped it."""
         gen = self._gen
-        if gen.partitioned is not None:
-            out = serve_knn_index_sharded(
-                gen.partitioned, points, q_bm, k,
-                mesh=self.index_mesh, plan_cache=gen.plan_cache,
-                delta=gen.delta(),
-            )
-        else:
-            out = serve_knn_batch(
-                gen.snapshot, points, q_bm, k,
-                plan_cache=gen.plan_cache, delta=gen.delta(),
-            )
-        pts = np.asarray(points, np.float32).reshape(-1, 2)
-        self._record(np.concatenate([pts, pts], axis=1), q_bm)
-        self.monitor.observe_counters(out["nodes_checked"], out["verified"])
+        with obs.span("wisk.serve_knn"):
+            if gen.partitioned is not None:
+                out = serve_knn_index_sharded(
+                    gen.partitioned, points, q_bm, k,
+                    mesh=self.index_mesh, plan_cache=gen.plan_cache,
+                    delta=gen.delta(),
+                )
+            else:
+                out = serve_knn_batch(
+                    gen.snapshot, points, q_bm, k,
+                    plan_cache=gen.plan_cache, delta=gen.delta(),
+                )
+            with obs.span("wisk.observe"):
+                pts = np.asarray(points, np.float32).reshape(-1, 2)
+                self._record(np.concatenate([pts, pts], axis=1), q_bm)
+                self.monitor.observe_counters(out["nodes_checked"], out["verified"])
         return out
 
     # ------------------------------------------------------------- updates
@@ -1194,10 +1201,13 @@ class LiveIndex:
         compiled subscription block (DESIGN.md §8): any standing filter they
         satisfy queues an (object_id, subscription_id) notification for
         ``drain_notifications()``."""
-        if self.result_cache is not None:
-            self.result_cache.invalidate()
-        ids = self._gen.delta_log.insert(locs, kw_ids)
-        self.subscriptions.match_arrivals(ids, locs, kw_ids=kw_ids)
+        with obs.span("wisk.insert"):
+            if self.result_cache is not None:
+                self.result_cache.invalidate()
+            with obs.span("wisk.delta_insert"):
+                ids = self._gen.delta_log.insert(locs, kw_ids)
+            with obs.span("wisk.geofence_match"):
+                self.subscriptions.match_arrivals(ids, locs, kw_ids=kw_ids)
         return ids
 
     def delete(self, ids) -> int:
@@ -1205,9 +1215,11 @@ class LiveIndex:
 
         Deletion never retracts a queued notification -- the object *did*
         arrive while the matching subscriptions were live (§8 contract)."""
-        if self.result_cache is not None:
-            self.result_cache.invalidate()
-        return self._gen.delta_log.delete(ids)
+        with obs.span("wisk.delete"):
+            if self.result_cache is not None:
+                self.result_cache.invalidate()
+            with obs.span("wisk.delta_delete"):
+                return self._gen.delta_log.delete(ids)
 
     # -------------------------------------------- continuous filters (§8)
     def subscribe(self, rect, kw_ids) -> int:
@@ -1227,7 +1239,16 @@ class LiveIndex:
         rebuild swaps (the subscription state lives on the front door, and
         the exactly-once mark rides the monotonic global id space, which a
         swap continues rather than restarts)."""
-        return self.subscriptions.drain()
+        with obs.span("wisk.drain"):
+            return self.subscriptions.drain()
+
+    # ------------------------------------------------------------ operator
+    @staticmethod
+    def stats() -> Dict[str, int]:
+        """The program's counters since the process started, summed over
+        every index in it (``repro.obs.totals``; README "Operating an
+        index")."""
+        return obs.totals()
 
     # ------------------------------------------------------------- rebuild
     def observed_workload(self):

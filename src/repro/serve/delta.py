@@ -50,6 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.query import _mbr_dist2_f32
 from ..core.types import GeoTextDataset, WiskIndex, ids_to_bitmap
 from .snapshot import IndexSnapshot
@@ -374,6 +375,7 @@ class DeltaLog:
         B = self.buffer.slots_per_leaf
         while B < max_need:
             B *= 2
+            obs.count("delta.grows")  # each doubling retraces the descents
         buf = self.buffer.grown(B)
         buf = dataclasses.replace(
             buf,
@@ -403,6 +405,7 @@ class DeltaLog:
                 # be lossy, so drop them for good (executors fall back to
                 # the exact full-width ins_bm path)
                 self.compact_ok = False
+                obs.count("delta.compact_fallbacks")
                 buf = dataclasses.replace(buf, ins_cbm=None, ins_sig=None)
 
         # widen the ancestor path per touched (level, node)

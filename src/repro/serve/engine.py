@@ -81,6 +81,7 @@ delta) replicated.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Dict, List, Optional
 
@@ -88,6 +89,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from .. import obs
 
 # round_up_bucket lives in core.query so construction (core.partition) can
 # share the exact same bucket discipline; re-exported here for callers
@@ -127,10 +130,13 @@ def _narrow_words(q_bm, delta, snap: IndexSnapshot, quantized: Optional[bool]):
 @jax.jit
 def _filter_frontier_level(mbrs, bms, q_rects, q_bm, frontier):
     """Gather frontier node tiles and run the Pallas frontier kernel."""
-    valid = frontier >= 0
-    safe = jnp.clip(frontier, 0, mbrs.shape[0] - 1)
-    surv = ops.filter_frontier(q_rects, q_bm, mbrs[safe], bms[safe], valid.astype(jnp.int8))
-    return surv, jnp.sum(valid, axis=1).astype(jnp.int32)
+    with jax.named_scope("filter"):
+        valid = frontier >= 0
+        safe = jnp.clip(frontier, 0, mbrs.shape[0] - 1)
+        surv = ops.filter_frontier(
+            q_rects, q_bm, mbrs[safe], bms[safe], valid.astype(jnp.int8)
+        )
+        return surv, jnp.sum(valid, axis=1).astype(jnp.int32)
 
 
 @jax.jit
@@ -139,20 +145,22 @@ def _filter_frontier_level_narrow(codes, bms, dict_x, dict_y, q_rects, wids, bit
     rank codes and only the query's packed bitmap word planes (the (M, F, W)
     slab shrinks to (M, F, Wp)), then runs the narrow Pallas kernel --
     bit-identical survivors (tests/test_query_parity.py)."""
-    valid = frontier >= 0
-    safe = jnp.clip(frontier, 0, codes.shape[0] - 1)
-    f_bm = bms[safe[:, :, None], wids[:, None, :]]  # (M, F, Wp)
-    surv = ops.filter_frontier_narrow(
-        q_rects, bits, codes[safe], f_bm, valid.astype(jnp.int8), dict_x, dict_y
-    )
-    return surv, jnp.sum(valid, axis=1).astype(jnp.int32)
+    with jax.named_scope("filter"):
+        valid = frontier >= 0
+        safe = jnp.clip(frontier, 0, codes.shape[0] - 1)
+        f_bm = bms[safe[:, :, None], wids[:, None, :]]  # (M, F, Wp)
+        surv = ops.filter_frontier_narrow(
+            q_rects, bits, codes[safe], f_bm, valid.astype(jnp.int8), dict_x, dict_y
+        )
+        return surv, jnp.sum(valid, axis=1).astype(jnp.int32)
 
 
 @jax.jit
 def _frontier_child_counts(child_counts, frontier, surv):
     """Per-query number of children the surviving frontier will expand to."""
-    safe = jnp.clip(frontier, 0, child_counts.shape[0] - 1)
-    return jnp.sum(jnp.where(surv > 0, child_counts[safe], 0), axis=1)
+    with jax.named_scope("expand"):
+        safe = jnp.clip(frontier, 0, child_counts.shape[0] - 1)
+        return jnp.sum(jnp.where(surv > 0, child_counts[safe], 0), axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("f_next",))
@@ -165,14 +173,15 @@ def _expand_frontier(child_table, frontier, surv, f_next: int):
     descent is lossless.
     """
     M, F = frontier.shape
-    safe = jnp.clip(frontier, 0, child_table.shape[0] - 1)
-    cand = jnp.where((surv > 0)[:, :, None], child_table[safe], -1).reshape(M, -1)
-    validc = cand >= 0
-    pos = jnp.cumsum(validc, axis=1) - 1
-    pos = jnp.where(validc & (pos < f_next), pos, f_next)  # f_next = trash slot
-    nxt = jnp.full((M, f_next + 1), -1, jnp.int32)
-    nxt = nxt.at[jnp.arange(M)[:, None], pos].set(cand, mode="drop")
-    return nxt[:, :f_next]
+    with jax.named_scope("expand"):
+        safe = jnp.clip(frontier, 0, child_table.shape[0] - 1)
+        cand = jnp.where((surv > 0)[:, :, None], child_table[safe], -1).reshape(M, -1)
+        validc = cand >= 0
+        pos = jnp.cumsum(validc, axis=1) - 1
+        pos = jnp.where(validc & (pos < f_next), pos, f_next)  # f_next = trash slot
+        nxt = jnp.full((M, f_next + 1), -1, jnp.int32)
+        nxt = nxt.at[jnp.arange(M)[:, None], pos].set(cand, mode="drop")
+        return nxt[:, :f_next]
 
 
 @functools.partial(jax.jit, static_argnames=("take", "n_leaf"))
@@ -479,23 +488,30 @@ def _retrieve_frontier(
     M = q_rects.shape[0]
     plan = cache.plan("skr", snap.n_levels - 1)
     descend = lambda p: _descend_frontier(snap, q_rects, q_bm, p, delta, words)
-    out = descend(plan)
+    with obs.span("wisk.descend"):
+        out = descend(plan)
     retried = cache.check_and_retry(plan, out[-1], descend)
     frontier, surv, nodes_checked, used, _ = retried or out
 
     n_leaf = snap.n_leaves
     take = min(max_leaves, n_leaf, int(frontier.shape[1]))
-    top_leaf, leaf_ok, overflow = _select_leaves_frontier(frontier, surv, take, n_leaf)
-    ids, counts, kw_scanned = _verify_leaves(
-        snap, q_rects, q_bm, top_leaf, leaf_ok, delta, fused, fused_variant, compact
-    )
+    with obs.span("wisk.verify"):
+        top_leaf, leaf_ok, overflow = _select_leaves_frontier(frontier, surv, take, n_leaf)
+        ids, counts, kw_scanned = _verify_leaves(
+            snap, q_rects, q_bm, top_leaf, leaf_ok, delta, fused, fused_variant, compact
+        )
+    with obs.span("wisk.fetch"):
+        ids, counts, nodes_checked, kw_scanned, overflow = fetched = [
+            np.asarray(a) for a in (ids, counts, nodes_checked, kw_scanned, overflow)
+        ]
+    obs.count("skr.d2h_bytes", sum(a.nbytes for a in fetched))
     return dict(
-        ids=np.asarray(ids),
-        counts=np.asarray(counts),
-        nodes_checked=np.asarray(nodes_checked, np.int64),
+        ids=ids,
+        counts=counts,
+        nodes_checked=nodes_checked.astype(np.int64),
         nodes_scanned=np.full((M,), sum(used), np.int64),
-        verified=np.asarray(kw_scanned),
-        overflow=np.asarray(overflow),
+        verified=kw_scanned,
+        overflow=overflow,
         frontier_widths=np.asarray(used, np.int32),
     )
 
@@ -674,7 +690,9 @@ def _knn_leaf_phase(
     Also returns ``rm``, the per-query minimum over bounded-out chunk slots
     of ``dc * (1 - _BF16_RISK_TOL)`` -- the bf16 retry guard's conservative
     lower bound on what a pruned leaf could still contain (inf under f32
-    serving or when nothing was pruned; see ``retrieve_knn``'s ``knn_dtype``).
+    serving or when nothing was pruned; see ``retrieve_knn``'s ``knn_dtype``),
+    and ``live``, the number of chunks in which any (query, leaf) pair was
+    still within its bound (the rest did no useful work).
     """
     M, F = leaf_d.shape
     d = jnp.where(frontier == probe_leaf[:, None], jnp.inf, leaf_d)
@@ -684,29 +702,34 @@ def _knn_leaf_phase(
     l_ch = jnp.moveaxis(leaf_s.reshape(M, nch, ch), 1, 0)
 
     def step(carry, inp):
-        top_d, top_id, lv, ver, pr, rm = carry
+        top_d, top_id, lv, ver, pr, rm, live = carry
         dc, lc = inp  # (M, ch)
         bound = top_d[:, k - 1]
         active = jnp.isfinite(dc) & (dc <= bound[:, None])
-        safe = jnp.clip(lc, 0, obj_x.shape[0] - 1)
-        ox, oy = obj_x[safe], obj_y[safe]  # (M, ch, OBJ)
-        oid = obj_id[safe]
-        kw, ikw = _chunk_kw(q_bm, obj_bm, delta, cbank, safe)
+        with jax.named_scope("gather"):
+            safe = jnp.clip(lc, 0, obj_x.shape[0] - 1)
+            ox, oy = obj_x[safe], obj_y[safe]  # (M, ch, OBJ)
+            oid = obj_id[safe]
+        with jax.named_scope("keyword"):
+            kw, ikw = _chunk_kw(q_bm, obj_bm, delta, cbank, safe)
         base_ok = oid >= 0
         if delta is not None:
-            base_ok = base_ok & (delta.base_alive[safe] > 0)
-            ox = jnp.concatenate([ox, delta.ins_x[safe]], axis=2)
-            oy = jnp.concatenate([oy, delta.ins_y[safe]], axis=2)
-            oid = jnp.concatenate([oid, delta.ins_id[safe]], axis=2)
-            kw = jnp.concatenate([kw, ikw], axis=2)
-            base_ok = jnp.concatenate([base_ok, delta.ins_id[safe] >= 0], axis=2)
-        dx = ox - points[:, 0][:, None, None]
-        dy = oy - points[:, 1][:, None, None]
-        od2 = dx * dx + dy * dy
-        valid = base_ok & kw & active[:, :, None]
-        cd = jnp.where(valid, od2, jnp.inf).reshape(M, -1)
-        cid = jnp.where(valid, oid, _ID_SENTINEL).reshape(M, -1)
-        top_d2, top_id2 = _merge_topk(top_d, top_id, cd, cid, kb)
+            with jax.named_scope("gather"):
+                base_ok = base_ok & (delta.base_alive[safe] > 0)
+                ox = jnp.concatenate([ox, delta.ins_x[safe]], axis=2)
+                oy = jnp.concatenate([oy, delta.ins_y[safe]], axis=2)
+                oid = jnp.concatenate([oid, delta.ins_id[safe]], axis=2)
+                kw = jnp.concatenate([kw, ikw], axis=2)
+                base_ok = jnp.concatenate([base_ok, delta.ins_id[safe] >= 0], axis=2)
+        with jax.named_scope("distance"):
+            dx = ox - points[:, 0][:, None, None]
+            dy = oy - points[:, 1][:, None, None]
+            od2 = dx * dx + dy * dy
+            valid = base_ok & kw & active[:, :, None]
+            cd = jnp.where(valid, od2, jnp.inf).reshape(M, -1)
+            cid = jnp.where(valid, oid, _ID_SENTINEL).reshape(M, -1)
+        with jax.named_scope("merge_topk"):
+            top_d2, top_id2 = _merge_topk(top_d, top_id, cd, cid, kb)
         lv = lv + jnp.sum(active, axis=1).astype(jnp.int32)
         ver = ver + jnp.sum(valid, axis=(1, 2)).astype(jnp.int32)
         pr = pr + jnp.sum(jnp.isfinite(dc) & ~active, axis=1).astype(jnp.int32)
@@ -714,14 +737,15 @@ def _knn_leaf_phase(
             jnp.isfinite(dc) & ~active, dc * (1.0 - _BF16_RISK_TOL), jnp.inf
         )
         rm = jnp.minimum(rm, jnp.min(lower, axis=1))
-        return (top_d2, top_id2, lv, ver, pr, rm), None
+        live = live + jnp.any(active).astype(jnp.int32)
+        return (top_d2, top_id2, lv, ver, pr, rm, live), None
 
     z = jnp.zeros((M,), jnp.int32)
     rm0 = jnp.full((M,), jnp.inf, jnp.float32)
-    (top_d, top_id, lv, ver, pr, rm), _ = jax.lax.scan(
-        step, (top_d, top_id, z, z, z, rm0), (d_ch, l_ch)
+    (top_d, top_id, lv, ver, pr, rm, live), _ = jax.lax.scan(
+        step, (top_d, top_id, z, z, z, rm0, jnp.int32(0)), (d_ch, l_ch)
     )
-    return top_d, top_id, lv, ver, pr, rm
+    return top_d, top_id, lv, ver, pr, rm, live
 
 
 def _descend_knn(
@@ -808,15 +832,18 @@ def _descend_knn(
 
     F = int(frontier.shape[1])
     ch = 4 if F % 4 == 0 else 1
-    top_d, top_id, lv, ver, pr, rm = _knn_leaf_phase(
-        points, q_bm, leaf_d, frontier, probe_leaf,
-        snap.leaf_obj_x, snap.leaf_obj_y, snap.leaf_obj_bm, snap.leaf_obj_id,
-        top_d, top_id, k, kb, ch, delta, cbank,
-    )
+    # a span only where this runs eagerly, not while a shard_map traces it
+    traced = isinstance(points, jax.core.Tracer)
+    with contextlib.nullcontext() if traced else obs.span("wisk.leaf_phase"):
+        top_d, top_id, lv, ver, pr, rm, live = _knn_leaf_phase(
+            points, q_bm, leaf_d, frontier, probe_leaf,
+            snap.leaf_obj_x, snap.leaf_obj_y, snap.leaf_obj_bm, snap.leaf_obj_id,
+            top_d, top_id, k, kb, ch, delta, cbank,
+        )
     result = (
         top_d, top_id, nodes_checked, verified + ver,
         leaves_verified + lv, pruned + pr, used,
-        jnp.minimum(risk_min, rm),
+        jnp.minimum(risk_min, rm), (F // ch, live),
     )
     return result, needs
 
@@ -1069,8 +1096,10 @@ def retrieve_knn(
     """
     if knn_dtype not in ("f32", "bf16"):
         raise ValueError(f"knn_dtype must be 'f32' or 'bf16', got {knn_dtype!r}")
-    points = jnp.asarray(points, jnp.float32)
-    q_bm = jnp.asarray(q_bm, jnp.uint32)
+    with obs.span("wisk.prep"):
+        words = _narrow_words(q_bm, delta, snap, quantized) if k > 0 else None
+        points = jnp.asarray(points, jnp.float32)
+        q_bm = jnp.asarray(q_bm, jnp.uint32)
     M = int(points.shape[0])
     if k <= 0:
         z = np.zeros(M, np.int64)
@@ -1081,16 +1110,16 @@ def retrieve_knn(
         )
     kb = round_up_bucket(k, min_topk_bucket)
     cache = plan_cache if plan_cache is not None else default_plan_cache(snap)
-    words = _narrow_words(q_bm, delta, snap, quantized)
     cbank = _snap_cbank(snap, compact)
     plan = cache.plan("knn", snap.n_levels - 1)
     descend = lambda p: _descend_knn(
         snap, points, q_bm, k, kb, p, delta, words, knn_dtype=knn_dtype, cbank=cbank
     )
-    out = descend(plan)
+    with obs.span("wisk.descend"):
+        out = descend(plan)
     retried = cache.check_and_retry(plan, out[-1], descend)
     (top_d, top_id, nodes_checked, verified, leaves_verified,
-     pruned, used, risk) = (retried or out)[0]
+     pruned, used, risk, (n_chunks, live_chunks)) = (retried or out)[0]
     if knn_dtype == "bf16":
         bound = np.asarray(top_d[:, k - 1])
         risk_np = np.asarray(risk)
@@ -1104,13 +1133,20 @@ def retrieve_knn(
             return exact
     fin = jnp.isfinite(top_d[:, :k])
     ids = jnp.where(fin, top_id[:, :k], -1)
+    with obs.span("wisk.fetch"):
+        ids, dist2, *counters, live_chunks = (np.asarray(a) for a in (
+            ids, top_d[:, :k], nodes_checked, verified, leaves_verified, pruned, live_chunks
+        ))
+    obs.count("knn.chunks", n_chunks)
+    obs.count("knn.live_chunks", int(live_chunks))
+    nodes_checked, verified, leaves_verified, pruned = (c.astype(np.int64) for c in counters)
     result = dict(
-        ids=np.asarray(ids),
-        dist2=np.asarray(top_d[:, :k]),
-        nodes_checked=np.asarray(nodes_checked, np.int64),
-        verified=np.asarray(verified, np.int64),
-        leaves_verified=np.asarray(leaves_verified, np.int64),
-        pruned=np.asarray(pruned, np.int64),
+        ids=ids,
+        dist2=dist2,
+        nodes_checked=nodes_checked,
+        verified=verified,
+        leaves_verified=leaves_verified,
+        pruned=pruned,
         frontier_widths=np.asarray(used, np.int32),
     )
     if knn_dtype == "bf16":
@@ -1198,11 +1234,12 @@ def retrieve(
     whenever the snapshot carries one; False forces the global full-width
     slab. Every combination is id- and counter-exact.
     """
-    q_rects = jnp.asarray(q_rects, jnp.float32)
-    q_bm = jnp.asarray(q_bm, jnp.uint32)
+    with obs.span("wisk.prep"):
+        words = _narrow_words(q_bm, delta, snap, quantized) if mode == "frontier" else None
+        q_rects = jnp.asarray(q_rects, jnp.float32)
+        q_bm = jnp.asarray(q_bm, jnp.uint32)
     if mode == "frontier":
         cache = plan_cache if plan_cache is not None else default_plan_cache(snap)
-        words = _narrow_words(q_bm, delta, snap, quantized)
         return _retrieve_frontier(
             snap, q_rects, q_bm, max_leaves, cache, delta, fused, words,
             fused_variant, compact,
@@ -1228,8 +1265,8 @@ def retrieve_workload(
 ):
     return retrieve(
         snap,
-        jnp.asarray(workload.rects),
-        jnp.asarray(workload.kw_bitmap),
+        workload.rects,
+        workload.kw_bitmap,
         max_leaves,
         mode=mode,
         plan_cache=plan_cache,

@@ -48,6 +48,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.query import round_up_bucket, sharded_bucket
 from ..kernels.ops import NEVER_RECT
 
@@ -145,16 +146,22 @@ class PlanCache:
         and grow the cache either way. Returns the retried descent output or
         None when the original descent stands."""
         if plan.widths is None:
+            obs.count("plan.exact_descents")
             self.observe(plan.tag, needs)  # exact descent: needs are host ints
             return None
-        if needs:
+        if not needs:
+            return None
+        with obs.span("wisk.sync"):
             maxima = np.asarray(jax.device_get(jnp.stack(list(needs))))
-            if np.any(maxima > np.asarray(plan.widths)):
-                self.observe(plan.tag, maxima)
+            if not np.any(maxima > np.asarray(plan.widths)):
+                return None
+            obs.count("plan.retries")
+            obs.count("plan.exact_descents")
+            self.observe(plan.tag, maxima)
+            with obs.span("wisk.redescend"):
                 out = descend(ExecutionPlan(tag=plan.tag, widths=None))
-                self.observe(plan.tag, out[-1])
-                return out
-        return None
+            self.observe(plan.tag, out[-1])
+            return out
 
 
 # Convenience registry for callers that don't manage planning state

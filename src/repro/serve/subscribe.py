@@ -50,6 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.types import bitmap_words, ids_to_bitmap
 from ..kernels.ops import NEVER_RECT, match_subscriptions
 
@@ -140,6 +141,7 @@ class SubscriptionIndex:
             slot = self._fill
             self._fill += 1
             if slot >= self.n_slots:
+                obs.count("subscribe.grows")
                 grown = self.n_slots * 2
                 self._rects = np.concatenate(
                     [self._rects,
