@@ -46,9 +46,10 @@ default the engine's ``serve/engine.py::_verify_leaves`` passes through: it
 compares the bank's byte size (``leaf_bank_bytes``, the ``obj_x/y/bm/id``
 rows) against ``ops.FUSED_VMEM_BANK_BYTES`` and picks the VMEM variant
 below the cutoff, the prefetch variant above it -- so the engine never
-falls back to the unfused HBM round-trip on bank-size grounds (only a live
-DeltaBuffer disables fusion). ``variant="vmem"``/``"prefetch"`` force
-either side for A/B rows and the beyond-VMEM oracle sweeps.
+falls back to the unfused HBM round-trip on bank-size grounds (with a live
+DeltaBuffer only its insert slots take the unfused verify).
+``variant="vmem"``/``"prefetch"`` force either side for A/B rows and the
+beyond-VMEM oracle sweeps.
 
 Compact-bank twins (DESIGN.md §3.5): ``fused_verify_compact`` /
 ``fused_verify_prefetch_compact`` verify against the leaf-local vocabulary

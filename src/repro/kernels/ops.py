@@ -99,8 +99,10 @@ def remap_query_words(q_bm, leaf_terms, leaves):
     Returns ``(q_cbm (M, T, Wl) u32, q_sig (M, T) u32)`` with ``q_sig`` the
     OR-fold of the remapped words -- a per-(query, slot) kill flag
     (``q_sig == 0`` means nothing in the leaf can match) and the query half
-    of the kernels' one-word signature prefilter. Traced (runs inside the
-    jitted descent after leaf selection).
+    of the kernels' one-word signature prefilter. Traced: every caller runs
+    it inside a compiled program -- the engine's verify stage
+    (serve/engine.py:``_verify_stage``, after leaf selection), the kNN leaf
+    steps and the sharded serving bodies.
     """
     q = jnp.asarray(q_bm, jnp.uint32)
     M, W = q.shape

@@ -83,7 +83,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -288,6 +288,31 @@ def _verify_delta_slots(q_rects, q_bm, top_leaf, leaf_ok, delta, q_cbm, q_sig):
     return ids, counts, kw_scanned
 
 
+class _LeafBank(NamedTuple):
+    """The snapshot arrays the leaf verify stage reads, as one pytree: the
+    (K, OBJ) object coordinates and ids, the full-width (K, OBJ, W) bitmap
+    slab, and the compact bank triple from ``_snap_cbank`` (None when the
+    stage verifies on the full-width slab)."""
+
+    obj_x: jnp.ndarray
+    obj_y: jnp.ndarray
+    obj_id: jnp.ndarray
+    obj_bm: jnp.ndarray
+    cbank: Optional[tuple]
+
+
+def _stage_inputs(snap: IndexSnapshot, fused, fused_variant: Optional[str],
+                  compact: Optional[bool]):
+    """The verify stage's ``_LeafBank`` on ``snap`` and its ``fused`` flag
+    and fused kernel variant with the callers' None (auto) resolved; an
+    ``"auto"`` variant is priced by the ops layer from the bank's shapes."""
+    bank = _LeafBank(
+        snap.leaf_obj_x, snap.leaf_obj_y, snap.leaf_obj_id, snap.leaf_obj_bm,
+        _snap_cbank(snap, compact),
+    )
+    return bank, fused is None or bool(fused), fused_variant or "auto"
+
+
 def _verify_leaves(
     snap: IndexSnapshot, q_rects, q_bm, top_leaf, leaf_ok, delta=None, fused=None,
     fused_variant: Optional[str] = None, compact: Optional[bool] = None,
@@ -321,29 +346,37 @@ def _verify_leaves(
     beyond VMEM keep the fused path instead of falling back to the unfused
     HBM round-trip. ``"vmem"``/``"prefetch"`` force a kernel (A/B rows,
     beyond-VMEM tests).
+
+    Traced inside the sharded bodies; the single-device executors run the
+    same math through the compiled ``_verify_stage``.
     """
-    if fused is None:
-        fused = True
-    cbank = _snap_cbank(snap, compact)
+    bank, fused, variant = _stage_inputs(snap, fused, fused_variant, compact)
+    return _verify_bank(bank, q_rects, q_bm, top_leaf, leaf_ok, delta, fused, variant)
+
+
+def _verify_bank(bank: _LeafBank, q_rects, q_bm, top_leaf, leaf_ok, delta, fused: bool,
+                 variant: str):
+    """``_verify_leaves``' body on a ``_LeafBank``, with ``fused`` and the
+    fused kernel ``variant`` resolved by ``_stage_inputs``."""
+    cbank = bank.cbank
     q_cbm = q_sig = None
     if cbank is not None:
         q_cbm, q_sig = ops.remap_query_words(q_bm, cbank[0], top_leaf)
-    variant = fused_variant if fused_variant is not None else "auto"
     if fused:
-        base_id = snap.leaf_obj_id
+        base_id = bank.obj_id
         if delta is not None:
             # deleted objects become pad slots for the fused base pass
-            base_id = jnp.where(delta.base_alive > 0, snap.leaf_obj_id, -1)
+            base_id = jnp.where(delta.base_alive > 0, bank.obj_id, -1)
         if cbank is not None:
             ids, kwv = ops.fused_gather_verify_compact(
                 q_rects, q_cbm, q_sig, top_leaf, leaf_ok.astype(jnp.int8),
-                snap.leaf_obj_x, snap.leaf_obj_y, cbank[1], cbank[2], base_id,
+                bank.obj_x, bank.obj_y, cbank[1], cbank[2], base_id,
                 variant=variant,
             )
         else:
             ids, kwv = ops.fused_gather_verify(
                 q_rects, q_bm, top_leaf, leaf_ok.astype(jnp.int8),
-                snap.leaf_obj_x, snap.leaf_obj_y, snap.leaf_obj_bm, base_id,
+                bank.obj_x, bank.obj_y, bank.obj_bm, base_id,
                 variant=variant,
             )
         counts = jnp.sum((ids >= 0).astype(jnp.int32), axis=1)
@@ -357,15 +390,15 @@ def _verify_leaves(
             kw_scanned = kw_scanned + d_kw
         return ids, counts, kw_scanned
     M = q_rects.shape[0]
-    cx = snap.leaf_obj_x[top_leaf].reshape(M, -1)
-    cy = snap.leaf_obj_y[top_leaf].reshape(M, -1)
-    cid = snap.leaf_obj_id[top_leaf].reshape(M, -1)
-    cval = (cid >= 0) & jnp.repeat(leaf_ok, snap.obj_per_leaf, axis=1)
+    OBJ = bank.obj_x.shape[1]
+    cx = bank.obj_x[top_leaf].reshape(M, -1)
+    cy = bank.obj_y[top_leaf].reshape(M, -1)
+    cid = bank.obj_id[top_leaf].reshape(M, -1)
+    cval = (cid >= 0) & jnp.repeat(leaf_ok, OBJ, axis=1)
     if delta is not None:
         alive = delta.base_alive[top_leaf].reshape(M, -1)
         cval = cval & (alive > 0)
     if cbank is not None:
-        OBJ = snap.obj_per_leaf
         Wl = cbank[1].shape[2]
         ccbm = cbank[1][top_leaf].reshape(M, -1, Wl)
         csig = cbank[2][top_leaf].reshape(M, -1)
@@ -386,7 +419,7 @@ def _verify_leaves(
             counts = counts + d_counts
             kw_scanned = kw_scanned + d_kw
         return ids, counts, kw_scanned
-    cbm = snap.leaf_obj_bm[top_leaf].reshape(M, -1, q_bm.shape[1])
+    cbm = bank.obj_bm[top_leaf].reshape(M, -1, q_bm.shape[1])
     if delta is not None:
         B = delta.slots_per_leaf
         ix = delta.ins_x[top_leaf].reshape(M, -1)
@@ -407,6 +440,39 @@ def _verify_leaves(
     )
     ids = jnp.where(match > 0, cid, -1)
     return ids, counts, kw_scanned
+
+
+@functools.partial(jax.jit, static_argnames=("take", "n_leaf", "fused", "variant"))
+def _verify_stage(
+    bank: _LeafBank, q_rects, q_bm, frontier, surv, delta, take: int, n_leaf: int,
+    fused: bool, variant: str,
+):
+    """The whole single-device verify stage as one program: leaf selection
+    (``_select_leaves_frontier``), the query-word remap, the ``base_alive``
+    mask, the fused kernel, the delta-slot verify and merge, and the counts.
+
+    One program per shape: ``bank.cbank`` and ``delta`` (None, with or
+    without ``ins_cbm``) enter as pytrees, so their structure keys the
+    compile cache alongside the static ``take``/``n_leaf``/``fused``/
+    ``variant``. Returns ``(ids, counts, kw_scanned, overflow)``.
+    """
+    top_leaf, leaf_ok, overflow = _select_leaves_frontier(frontier, surv, take, n_leaf)
+    ids, counts, kw_scanned = _verify_bank(
+        bank, q_rects, q_bm, top_leaf, leaf_ok, delta, fused, variant
+    )
+    return ids, counts, kw_scanned, overflow
+
+
+def _run_verify_stage(
+    snap: IndexSnapshot, q_rects, q_bm, frontier, surv, take: int, delta, fused,
+    fused_variant: Optional[str], compact: Optional[bool],
+):
+    """``_verify_stage`` on ``snap`` with the caller's knobs resolved."""
+    bank, fused, variant = _stage_inputs(snap, fused, fused_variant, compact)
+    return _verify_stage(
+        bank, q_rects, q_bm, frontier, surv, delta, take=take, n_leaf=snap.n_leaves,
+        fused=fused, variant=variant,
+    )
 
 
 def _root_frontier(snap: IndexSnapshot, M: int) -> jnp.ndarray:
@@ -493,12 +559,10 @@ def _retrieve_frontier(
     retried = cache.check_and_retry(plan, out[-1], descend)
     frontier, surv, nodes_checked, used, _ = retried or out
 
-    n_leaf = snap.n_leaves
-    take = min(max_leaves, n_leaf, int(frontier.shape[1]))
+    take = min(max_leaves, snap.n_leaves, int(frontier.shape[1]))
     with obs.span("wisk.verify"):
-        top_leaf, leaf_ok, overflow = _select_leaves_frontier(frontier, surv, take, n_leaf)
-        ids, counts, kw_scanned = _verify_leaves(
-            snap, q_rects, q_bm, top_leaf, leaf_ok, delta, fused, fused_variant, compact
+        ids, counts, kw_scanned, overflow = _run_verify_stage(
+            snap, q_rects, q_bm, frontier, surv, take, delta, fused, fused_variant, compact
         )
     with obs.span("wisk.fetch"):
         ids, counts, nodes_checked, kw_scanned, overflow = fetched = [
@@ -1173,14 +1237,13 @@ def _retrieve_dense(
             active = (hit.astype(jnp.int8) @ snap.child_matrix[li] > 0).astype(jnp.int8)
         else:
             leaf_hit = hit
-    # pick up to max_leaves relevant leaves per query (lowest leaf id first)
-    score = leaf_hit.astype(jnp.int32)
-    take = min(max_leaves, score.shape[1])
-    top_val, top_leaf = jax.lax.top_k(score, take)  # (M, L)
-    leaf_ok = top_val > 0
-    overflow = jnp.maximum(jnp.sum(score, axis=1) - take, 0)
-    ids, counts, kw_scanned = _verify_leaves(
-        snap, q_rects, q_bm, top_leaf, leaf_ok, delta, fused, compact=compact
+    # up to max_leaves relevant leaves per query (lowest leaf id first): the
+    # frontier stage's selection over a frontier of every leaf
+    n_leaf = leaf_hit.shape[1]
+    every_leaf = jnp.broadcast_to(jnp.arange(n_leaf, dtype=jnp.int32), (M, n_leaf))
+    ids, counts, kw_scanned, overflow = _run_verify_stage(
+        snap, q_rects, q_bm, every_leaf, leaf_hit.astype(jnp.int8),
+        min(max_leaves, n_leaf), delta, fused, None, compact,
     )
     return dict(
         ids=np.asarray(ids),

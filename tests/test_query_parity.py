@@ -8,6 +8,8 @@ small-``max_leaves`` overflow. Index construction here is deterministic
 (grid clusters + spatial grouping) so the suite is fast and seed-stable --
 it does not run the DQN packer.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -325,3 +327,143 @@ def test_narrow_descent_engine_parity(seed, levels):
             np.asarray(wide[key]), np.asarray(narrow[key]), err_msg=key)
         np.testing.assert_array_equal(
             np.asarray(narrow[key]), np.asarray(auto[key]), err_msg=key)
+
+
+# ------------------------- the compiled verify stage (serve.engine._verify_stage)
+_STAGE_N = 1200  # objects in the stage cases' collection; inserts get ids from here
+
+
+@functools.lru_cache(maxsize=None)
+def _stage_world():
+    """A compact-bank snapshot and three live states: no delta, a delta whose
+    inserts stay inside their leaves' dictionaries (``ins_cbm`` kept), and a
+    delta after the compact fallback (``ins_cbm`` dropped). Deletes hit base
+    objects and one buffered insert in both deltas."""
+    from repro.serve.delta import DeltaLog
+
+    ds = make_dataset("fs", n=_STAGE_N, seed=7)
+    index, clusters = _build_index(ds, g=5, levels=2)
+    snap = IndexSnapshot.build(index, ds)
+    assert snap.has_compact_bank
+    rng = np.random.default_rng(3)
+    src = rng.choice(ds.n, 6, replace=False)
+    locs = ds.locs[src].astype(np.float32)
+    probe = DeltaLog(index, ds, snap)
+    pid = probe.insert(locs, ds.kw_ids[src])
+    ins_id = np.asarray(probe.buffer.ins_id)
+    leaves = [int(np.argwhere(ins_id == int(i))[0, 0]) for i in pid]
+    terms = np.asarray(snap.leaf_terms)
+    kw = np.full((src.size, 2), -1, np.int32)
+    for i, lf in enumerate(leaves):
+        t = terms[lf][terms[lf] >= 0][:2]
+        kw[i, : t.size] = t
+    deltas = {"none": None}
+    for name in ("cbm", "nocbm"):
+        log = DeltaLog(index, ds, snap)
+        new = log.insert(locs, kw)
+        if name == "nocbm":
+            fresh = np.setdiff1d(np.arange(ds.vocab_size), terms[leaves[0]])
+            log.insert(locs[:1], np.array([[int(fresh[0])]], np.int32))
+        log.delete(np.concatenate([np.arange(0, 300, 11), new[-1:]]))
+        assert (log.buffer.ins_cbm is not None) == (name == "cbm"), name
+        deltas[name] = log.buffer
+    # pin the first queries onto the inserts so the delta slots match
+    wl = make_workload(ds, m=32, dist="MIX", seed=21)
+    R = np.asarray(wl.rects).copy()
+    B = np.asarray(wl.kw_bitmap).copy()
+    for i in range(4):
+        x, y = locs[i]
+        R[i] = (x - 0.05, y - 0.05, x + 0.05, y + 0.05)
+        B[i] = 0
+        for t in kw[i][kw[i] >= 0]:
+            B[i, t >> 5] |= np.uint32(1) << np.uint32(t & 31)
+    wide = make_workload(ds, m=32, dist="UNI", region_frac=0.2, n_keywords=4, seed=9)
+    return snap, clusters.k, deltas, (R, B), (np.asarray(wide.rects), np.asarray(wide.kw_bitmap))
+
+
+def _stage_inputs(snap, delta, R, B, m):
+    """The exact-mode frontier descent's leaf frontier and survivors."""
+    import jax.numpy as jnp
+
+    from repro.serve import engine
+    from repro.serve.plan import ExecutionPlan
+
+    q_rects = jnp.asarray(R[:m], jnp.float32)
+    q_bm = jnp.asarray(B[:m], jnp.uint32)
+    frontier, surv, *_ = engine._descend_frontier(
+        snap, q_rects, q_bm, ExecutionPlan(tag="skr", widths=None), delta
+    )
+    return q_rects, q_bm, frontier, surv
+
+
+@pytest.mark.parametrize("m", [1, 8, 32])
+@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("compact", [True, False])
+@pytest.mark.parametrize("delta_kind,overflow", [
+    ("none", False), ("cbm", False), ("nocbm", False), ("cbm", True),
+])
+def test_compiled_verify_stage_matches_eager(delta_kind, overflow, compact, fused, m):
+    """The compiled verify stage (leaf selection, remap, base_alive mask,
+    fused kernel, delta-slot merge and counts in one program) returns exactly
+    what the eager composition of ``_select_leaves_frontier`` and
+    ``_verify_leaves`` returns: the same ids in the same slots, the same
+    counts, ``verified`` and ``overflow``."""
+    from repro.serve import engine
+
+    snap, k, deltas, pinned, wide = _stage_world()
+    delta = deltas[delta_kind]
+    R, B = wide if overflow else pinned
+    max_leaves = 2 if overflow else k
+    q_rects, q_bm, frontier, surv = _stage_inputs(snap, delta, R, B, m)
+    take = min(max_leaves, snap.n_leaves, int(frontier.shape[1]))
+    got = engine._run_verify_stage(
+        snap, q_rects, q_bm, frontier, surv, take, delta, fused, None, compact
+    )
+    top_leaf, leaf_ok, want_over = engine._select_leaves_frontier(
+        frontier, surv, take, snap.n_leaves
+    )
+    want = engine._verify_leaves(
+        snap, q_rects, q_bm, top_leaf, leaf_ok, delta, fused, None, compact
+    ) + (want_over,)
+    for key, g, w in zip(("ids", "counts", "kw_scanned", "overflow"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=key)
+    if overflow and m > 1:
+        assert np.asarray(got[3]).sum() > 0  # the case actually spills
+    if delta_kind != "none" and not overflow:
+        assert (np.asarray(got[0]) >= _STAGE_N).any()  # a buffered insert matched
+
+
+def test_compiled_verify_stage_does_not_retrace():
+    """A call at shapes already seen reuses the stage program: the jit cache
+    neither grows nor lowers anything, also after an update replaces the
+    delta with a new buffer of the same structure and shapes."""
+    import jax
+
+    from repro.serve import engine
+    from repro.serve.delta import DeltaLog
+
+    lowered = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _s, **_k: lowered.append(event)
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration" else None
+    )
+    ds = make_dataset("fs", n=1000, seed=8)
+    index, clusters = _build_index(ds, g=5, levels=2)
+    snap = IndexSnapshot.build(index, ds)
+    wl = make_workload(ds, m=8, dist="MIX", seed=22)
+    log = DeltaLog(index, ds, snap)
+    log.insert(ds.locs[:2].astype(np.float32), ds.kw_ids[:2])
+    for delta in (None, log.buffer):
+        retrieve_workload(snap, wl, max_leaves=clusters.k, delta=delta)  # settles widths
+        retrieve_workload(snap, wl, max_leaves=clusters.k, delta=delta)
+        size, n_lowered = engine._verify_stage._cache_size(), len(lowered)
+        assert size > 0
+        out = retrieve_workload(snap, wl, max_leaves=clusters.k, delta=delta)
+        assert engine._verify_stage._cache_size() == size
+        assert len(lowered) == n_lowered, lowered[n_lowered:]
+    log.delete(np.arange(3))  # a new buffer, same slots per leaf
+    n_lowered = len(lowered)  # the delete's own update programs are not the stage's
+    retrieve_workload(snap, wl, max_leaves=clusters.k, delta=log.buffer)
+    assert engine._verify_stage._cache_size() == size
+    assert len(lowered) == n_lowered, lowered[n_lowered:]
+    assert out["ids"].shape[0] == 8

@@ -140,3 +140,35 @@ def test_wrappers_compile_on_tpu_backend(monkeypatch):
     assert ops._interpret() == (jax.default_backend() == "cpu")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert ops._interpret() is False
+
+
+@pytest.mark.parametrize("live_delta", [False, True])
+def test_verify_stage_compiles_for_v5e(one_chip, monkeypatch, live_delta):
+    """The engine's whole single-device verify stage (``_verify_stage``: leaf
+    selection, query-word remap, fused compact kernel and, with a live delta
+    after the compact fallback, the full-width delta-slot verify and merge)
+    compiles as one program for v5e at the serving widths."""
+    from repro.serve import engine
+    from repro.serve.delta import DeltaBuffer
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bank = engine._LeafBank(
+        s((K, OBJ), f32), s((K, OBJ), f32), s((K, OBJ), i32), s((K, OBJ, W), u32),
+        (s((K, 32 * WL), i32), s((K, OBJ, WL), u32), s((K, OBJ), u32)),
+    )
+    delta = None
+    if live_delta:
+        delta = DeltaBuffer(
+            aug_mbrs=[s((K, 4), f32)], aug_bms=[s((K, W), u32)],
+            ins_x=s((K, B), f32), ins_y=s((K, B), f32), ins_bm=s((K, B, W), u32),
+            ins_id=s((K, B), i32), base_alive=s((K, OBJ), i8), slots_per_leaf=B,
+        )
+    compiled = engine._verify_stage.lower(
+        bank, s((M, 4), f32), s((M, W), u32), s((M, F), i32), s((M, F), i8), delta,
+        take=T, n_leaf=K, fused=True, variant="auto",
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == (2 if live_delta else 1)
